@@ -76,6 +76,9 @@ def _run_all() -> None:
 
 
 def main(argv=None) -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument(
         "--metric-mode",

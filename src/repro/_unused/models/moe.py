@@ -27,7 +27,6 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.sharding.rules import shard
-from repro.sharding.smap import shard_map as smap_shard_map
 from .layers import cdtype, dense_init, pdtype
 
 __all__ = ["moe_init", "moe_axes", "moe_apply", "moe_capacity"]
@@ -252,11 +251,12 @@ def _moe_apply_ep(p, x: jnp.ndarray, cfg: ModelConfig, ep_axis: str):
         out = jax.lax.psum(out, ep_axis)
         return out.reshape(Bl, Sl, d)
 
-    routed = smap_shard_map(
+    routed = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(x_spec, P(), wi_spec, wi_spec, wo_spec),
         out_specs=x_spec,
+        check_vma=False,
     )(x, p["router"]["w"], p["wi"], p["wg"], p["wo"])
 
     routed = _add_shared(p, x, routed, cfg)

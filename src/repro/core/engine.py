@@ -129,13 +129,11 @@ class DecoderEngine:
     block_axes: mesh axes carrying the lane (flattened frames × blocks) axis.
         ``None`` resolves the ``"blocks"`` logical-axis rule of
         :mod:`repro.sharding.rules` against the mesh (``("pod", "data")``
-        on a multi-pod mesh, ``("data",)`` otherwise).
-    shard_dispatch: how a mesh-bound launch is driven —
-        ``"constraint"`` (default) places the packed lanes with a
-        ``NamedSharding`` and lets pjit partition the launch;
-        ``"shard_map"`` wraps it in :func:`repro.sharding.smap.shard_map`,
-        each shard decoding its local lanes explicitly. Both are bit-exact
-        to the unsharded decode; validated eagerly at construction
+        on a multi-pod mesh, ``("data",)`` otherwise). A mesh-bound launch
+        runs under :func:`repro.sharding.smap.lane_shard_map`, each shard
+        decoding its local lanes — the Pallas kernels cannot be partitioned
+        automatically, and the lanes need no partitioning beyond the split.
+        The binding is validated eagerly at construction
         (:func:`repro.kernels.ops.check_mesh_launch`).
     """
 
@@ -145,7 +143,6 @@ class DecoderEngine:
         *,
         mesh=None,
         block_axes: tuple[str, ...] | None = ("data",),
-        shard_dispatch: str = "constraint",
     ):
         from .pbvd import PBVDConfig  # local import: pbvd re-exports the engine
 
@@ -160,11 +157,10 @@ class DecoderEngine:
 
                 block_axes = block_mesh_axes(mesh)
         self.block_axes = tuple(block_axes)
-        self.shard_dispatch = shard_dispatch
         # eager: a bad mesh binding fails when the engine is BUILT, with a
         # clear error naming the axis/backend — never inside a pooled launch
         self.n_shards = (
-            check_mesh_launch(mesh, self.block_axes, self.cfg.backend, dispatch=shard_dispatch)
+            check_mesh_launch(mesh, self.block_axes, self.cfg.backend)
             if mesh is not None
             else 1
         )
@@ -347,8 +343,8 @@ class DecoderEngine:
         axis (one entry for plain decodes); lanes beyond the real blocks are
         padding the backend trims. With a mesh bound, the lane axis arrives
         pre-padded to :meth:`_lane_budget` (every caller rounds once, before
-        launch) and is sharded over ``block_axes`` by the configured
-        dispatch — collective-free either way, since blocks never interact.
+        launch) and is split over ``block_axes`` by ``shard_map`` —
+        collective-free, since blocks never interact.
         """
         cfg = self.cfg
         launch_kwargs = dict(
@@ -369,7 +365,7 @@ class DecoderEngine:
                 blocks, self.spec.code, frame_counts=frame_counts, **launch_kwargs
             )
 
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.sharding.smap import lane_shard_map
 
         B = blocks.shape[2]
         if B % self.n_shards:
@@ -379,30 +375,19 @@ class DecoderEngine:
                 f"lane axis {B} not divisible into {self.n_shards} shards; "
                 f"callers must pad to _lane_budget before launch"
             )
-        if self.shard_dispatch == "shard_map":
-            from repro.sharding.smap import lane_shard_map
+        # each shard decodes its B/n_shards local lanes independently;
+        # per-shard outputs must be uniform in shape, so the pad-lane trim
+        # happens ONCE on the stitched result (frame_counts stays a host-side
+        # concept — the mapped body decodes every local lane)
+        code = self.spec.code
 
-            # each shard decodes its B/n_shards local lanes independently;
-            # per-shard outputs must be uniform in shape, so the pad-lane
-            # trim happens ONCE on the stitched result (frame_counts stays a
-            # host-side concept — the mapped body decodes every local lane)
-            code = self.spec.code
+        def _local(y_local):
+            return pbvd_decode_blocks(y_local, code, **launch_kwargs)
 
-            def _local(y_local):
-                return pbvd_decode_blocks(y_local, code, **launch_kwargs)
-
-            bits = lane_shard_map(
-                _local, mesh=self.mesh, axes=self.block_axes, in_rank=3, out_rank=2
-            )(blocks)
-            return bits[:, : sum(frame_counts)]
-        # "constraint": commit the packed lanes to the mesh placement and let
-        # pjit partition the launch; the backend's n_real trim runs inside jit
-        blocks = jax.lax.with_sharding_constraint(
-            blocks, NamedSharding(self.mesh, P(None, None, self.block_axes))
-        )
-        return pbvd_decode_blocks(
-            blocks, self.spec.code, frame_counts=frame_counts, **launch_kwargs
-        )
+        bits = lane_shard_map(
+            _local, mesh=self.mesh, axes=self.block_axes, in_rank=3, out_rank=2
+        )(blocks)
+        return bits[:, : sum(frame_counts)]
 
 
 class DecoderSession:

@@ -66,9 +66,9 @@ class PBVDConfig:
     one stage per step; ``"prefix"`` composes ``tb_chunk``-stage survivor
     maps in parallel and cuts the serial chain to ceil(T/tb_chunk) steps —
     bit-exact to serial for every chunk size. The default ``"auto"``
-    resolves to the backend's declared measured-fastest mode (serial on
-    ``ref``, prefix on the Pallas kernels), so picking a backend no longer
-    requires knowing the benchmark table.
+    resolves to the backend's declared ``preferred_tb_mode`` in the kernel
+    registry (serial on all three backends today), so picking a backend no
+    longer requires knowing the benchmark table.
 
     ``acs_radix`` selects the forward-ACS step (the
     :data:`~repro.kernels.registry.ACS_RADIX` contract): ``2`` is the
@@ -242,20 +242,16 @@ def decode_stream_sharded(
     mesh: jax.sharding.Mesh,
     *,
     block_axes: tuple[str, ...] | None = ("data",),
-    shard_dispatch: str = "constraint",
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Distributed stream decode: thin wrapper over a mesh-bound engine.
 
     ``block_axes=None`` resolves the ``"blocks"`` logical-axis rule against
-    the mesh; ``shard_dispatch`` picks the lane dispatch path (see
-    :class:`~repro.core.engine.DecoderEngine`).
+    the mesh (see :class:`~repro.core.engine.DecoderEngine`).
     """
     from .engine import DecoderEngine
 
-    engine = DecoderEngine(
-        cfg, mesh=mesh, block_axes=block_axes, shard_dispatch=shard_dispatch
-    )
+    engine = DecoderEngine(cfg, mesh=mesh, block_axes=block_axes)
     return engine.decode(y, n_bits, interpret=interpret)
 
 

@@ -29,6 +29,7 @@ Validated bit-exactly against the two-kernel path and the jnp oracle
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -52,11 +53,14 @@ from .traceback import DEFAULT_TB_CHUNK, _prefix_traceback_phases, prefix_chunk_
 
 __all__ = ["pbvd_fused_pallas", "DEFAULT_SYM_CHUNK"]
 
-# Stages per double-buffered symbol tile (radix-4 path): the HBM read of the
-# next tile overlaps the current tile's ACS compute. Even (radix-4 pairs
-# never straddle a tile) and big enough to amortize the DMA issue cost; the
-# 2× scratch is 2·64·R·TILE symbol bytes — see DESIGN.md §10 for the model.
+# Stages per double-buffered symbol tile (radix-4 and matrix paths): the HBM
+# read of the next tile overlaps the current tile's ACS compute. Big enough
+# to amortize the DMA issue cost; the 2× scratch is 2·64·R·TILE symbol bytes
+# plus one 32-bit widened tile — see DESIGN.md §10 for the model.
 DEFAULT_SYM_CHUNK = 64
+# Tile alignment in stages: a tile of 32·j stages is 32·j·R flat symbol rows,
+# a multiple of the TPU sublane tiling for 8-, 16- and 32-bit dtypes alike.
+_SYM_ALIGN = 32
 
 
 def _acs_phase(
@@ -89,98 +93,14 @@ def _acs_phase(
     pm_ref[...] = pm
 
 
-def _acs_phase_r4_dbuf(
-    y_hbm,  # (T_pad, R, B) symbols, HBM/ANY — in their ORIGINAL dtype
-    bt,  # lane-tile index of this program instance
-    pm_ref,  # VMEM scratch (N, TILE)
-    sp_write,  # per-stage survivor-word writer (odd trailing stage)
-    sp_write_pair,  # per-step writer: (flat stage, words1, words2)
-    sym_ref,  # VMEM scratch (2, SYM, R, TILE), y dtype — the double buffer
-    sem_ref,  # DMA semaphores (2,)
-    *,
-    code: ConvCode,
-    n_stages: int,
-    acc_dtype,
-    norm_every: int,
-    clip_qmax: int | None,
-    sym_chunk: int,
-):
-    """Phase 1 (radix 4): stage-fused ACS with a double-buffered symbol pipeline.
-
-    Symbols stay in HBM in their quantized dtype; while the radix-4
-    butterflies of tile c compute, the DMA engine prefetches tile c+1 into
-    the other half of the double buffer, so the HBM read of ``ys`` overlaps
-    ACS compute instead of serializing with it (and the HBM traffic stays at
-    the narrow symbol width — the cast to 32-bit VPU registers happens after
-    the VMEM load). The wrapper pads T to a ``sym_chunk`` multiple so every
-    DMA has static shape; the compute loops stop at the true ``n_stages``.
-    """
-    tile = pm_ref.shape[-1]
-    T = n_stages
-    n_chunks = -(-T // sym_chunk)
-
-    def dma(c, slot):
-        return pltpu.make_async_copy(
-            y_hbm.at[pl.ds(c * sym_chunk, sym_chunk), :, pl.ds(bt * tile, tile)],
-            sym_ref.at[slot],
-            sem_ref.at[slot],
-        )
-
-    pm_ref[...] = jnp.zeros_like(pm_ref)
-    pm = pm_ref[...]
-    dma(0, 0).start()
-    for c in range(n_chunks):  # static chunk count: python-level pipeline
-        slot = c % 2
-        if c + 1 < n_chunks:
-            dma(c + 1, (c + 1) % 2).start()  # prefetch overlaps this chunk
-        dma(c, slot).wait()
-        lo = c * sym_chunk
-        hi = min(lo + sym_chunk, T)
-        step_base = lo // 2  # sym_chunk is even: pairs never straddle tiles
-
-        def load(row, n_rows, slot=slot):
-            # widen (and clip, narrow modes — see acs_forward_ref; in-kernel
-            # because the HBM copy keeps the wire dtype) at the VMEM read
-            y_t = sym_ref[slot, pl.ds(row, n_rows)].astype(acc_dtype)
-            if clip_qmax is not None:
-                y_t = jnp.clip(y_t, -clip_qmax, clip_qmax)
-            return y_t
-
-        def pair_body(s, pm, step_base=step_base):
-            y_pair = load(2 * s, 2)  # (2, R, TILE)
-            new_pm, dec1, dec2 = radix4_stage_pair(
-                pm, y_pair[0], y_pair[1], code, acc_dtype, tile
-            )
-            if norm_every:  # cadence counts GLOBAL fused steps
-                new_pm = jax.lax.cond(
-                    (step_base + s) % norm_every == norm_every - 1,
-                    _min_subtract,
-                    lambda p: p,
-                    new_pm,
-                )
-            sp_write_pair(
-                lo + 2 * s, _pack_plane(dec1, tile), _pack_plane(dec2, tile)
-            )
-            return new_pm
-
-        pm = jax.lax.fori_loop(0, (hi - lo) // 2, pair_body, pm, unroll=False)
-        if (hi - lo) % 2:
-            # trailing radix-2 step (odd T, last tile only); narrow modes
-            # min-subtract unconditionally — uniform shift, budget-safe
-            pm, dec = radix2_stage(pm, load(hi - 1 - lo, 1)[0], code, acc_dtype, tile)
-            if norm_every:
-                pm = _min_subtract(pm)
-            sp_write(hi - 1, _pack_plane(dec, tile))
-    pm_ref[...] = pm
-
-
-def _acs_phase_mat_dbuf(
-    y_hbm,  # (T_pad, R, B) symbols, HBM/ANY — in their ORIGINAL dtype
+def _acs_phase_dbuf(
+    y_hbm,  # (T_pad·R, B) stage-major symbol rows, HBM/ANY — in their WIRE dtype
     bt,  # lane-tile index of this program instance
     pm_ref,  # VMEM scratch (N, TILE)
     sp_write,  # per-stage survivor-word writer (trailing T mod k stages)
     sp_write_multi,  # per-step writer: (flat stage, [k packed planes])
-    sym_ref,  # VMEM scratch (2, SYM, R, TILE), y dtype — the double buffer
+    sym_ref,  # VMEM scratch (2, SYM·R, TILE), wire dtype — the double buffer
+    wide_ref,  # VMEM scratch (SYM·R, TILE), acc dtype — the widened tile
     sem_ref,  # DMA semaphores (2,)
     *,
     code: ConvCode,
@@ -190,25 +110,38 @@ def _acs_phase_mat_dbuf(
     clip_qmax: int | None,
     sym_chunk: int,
     k: int,
+    step,  # (pm, [k (R, TILE) stage rows]) → (new_pm, [k decision planes])
 ):
-    """Phase 1 (matrix): k-stage tropical-matmul ACS on the double-buffered
-    symbol pipeline of :func:`_acs_phase_r4_dbuf` — the DMA prefetch of
-    symbol tile c+1 overlaps tile c's matrix steps. The wrapper rounds
-    ``sym_chunk`` to a k-multiple, so steps never straddle tiles and the
-    T mod k trailing stages (radix-2, unconditional min-subtract in narrow
-    modes — a uniform budget-safe shift) fall in the last tile only,
-    matching the ref scan's step/trailing split exactly.
+    """Phase 1 (k-stage steps): ACS on a double-buffered symbol pipeline.
+
+    Symbols stay in HBM in their quantized wire dtype; while the steps of
+    tile c compute, the DMA engine prefetches tile c+1 into the other half
+    of the double buffer, so the HBM read overlaps ACS compute. Each landed
+    tile is widened (and clipped, narrow modes — see acs_forward_ref) once
+    into a 32-bit scratch, from which the steps read their ``k·R`` rows.
+    The symbols travel as flat stage-major rows ``(T_pad·R, B)`` and the
+    wrapper makes ``sym_chunk`` a multiple of 32 and of k: every DMA slice
+    is then aligned to the sublane tiling of any wire dtype (a 3-D
+    ``(SYM, R, TILE)`` slice is not, for R=3 or for int8 symbols), steps
+    never straddle tiles, and the T mod k trailing stages (radix-2,
+    unconditional min-subtract in narrow modes — a uniform budget-safe
+    shift) fall in the last tile only, matching the ref scan's split.
     """
     tile = pm_ref.shape[-1]
+    R = code.R
     T = n_stages
     n_chunks = -(-T // sym_chunk)
 
     def dma(c, slot):
         return pltpu.make_async_copy(
-            y_hbm.at[pl.ds(c * sym_chunk, sym_chunk), :, pl.ds(bt * tile, tile)],
+            y_hbm.at[pl.ds(c * sym_chunk * R, sym_chunk * R), pl.ds(bt * tile, tile)],
             sym_ref.at[slot],
             sem_ref.at[slot],
         )
+
+    def stage_rows(t, n):  # n (R, TILE) stage rows from tile-local stage t
+        rows = wide_ref[pl.ds(t * R, n * R)]
+        return [rows[i * R : (i + 1) * R] for i in range(n)]
 
     pm_ref[...] = jnp.zeros_like(pm_ref)
     pm = pm_ref[...]
@@ -218,23 +151,16 @@ def _acs_phase_mat_dbuf(
         if c + 1 < n_chunks:
             dma(c + 1, (c + 1) % 2).start()  # prefetch overlaps this chunk
         dma(c, slot).wait()
+        y_t = sym_ref[slot].astype(acc_dtype)
+        if clip_qmax is not None:
+            y_t = jnp.clip(y_t, -clip_qmax, clip_qmax)
+        wide_ref[...] = y_t
         lo = c * sym_chunk
         hi = min(lo + sym_chunk, T)
         step_base = lo // k  # sym_chunk is a k-multiple
 
-        def load(row, n_rows, slot=slot):
-            # widen (and clip, narrow modes) at the VMEM read, as in the
-            # radix-4 pipeline — the HBM copy keeps the wire dtype
-            y_t = sym_ref[slot, pl.ds(row, n_rows)].astype(acc_dtype)
-            if clip_qmax is not None:
-                y_t = jnp.clip(y_t, -clip_qmax, clip_qmax)
-            return y_t
-
         def step_body(s, pm, step_base=step_base, lo=lo):
-            ys = load(k * s, k)  # (k, R, TILE)
-            new_pm, planes = matrix_step(
-                pm, [ys[i] for i in range(k)], code, acc_dtype, tile, k
-            )
+            new_pm, planes = step(pm, stage_rows(k * s, k))
             if norm_every:  # cadence counts GLOBAL k-stage steps
                 new_pm = jax.lax.cond(
                     (step_base + s) % norm_every == norm_every - 1,
@@ -247,7 +173,7 @@ def _acs_phase_mat_dbuf(
 
         pm = jax.lax.fori_loop(0, (hi - lo) // k, step_body, pm, unroll=False)
         for t in range(hi - lo - (hi - lo) % k, hi - lo):
-            pm, dec = radix2_stage(pm, load(t, 1)[0], code, acc_dtype, tile)
+            pm, dec = radix2_stage(pm, stage_rows(t, 1)[0], code, acc_dtype, tile)
             if norm_every:
                 pm = _min_subtract(pm)
             sp_write(lo + t, _pack_plane(dec, tile))
@@ -271,27 +197,9 @@ def _run_acs_phase(
     clip_qmax: int | None,
     sym_chunk: int,
 ):
-    """Dispatch phase 1: VMEM-resident radix-2, or a double-buffered fused
-    path (stage-fused radix-4 butterflies, or k-stage matrix steps)."""
-    if impl == "matrix":
-        sym_ref, sem_ref = extra_scratch
-        _acs_phase_mat_dbuf(
-            y_ref,
-            pl.program_id(0),
-            pm_ref,
-            sp_write,
-            sp_write_multi,
-            sym_ref,
-            sem_ref,
-            code=code,
-            n_stages=n_stages,
-            acc_dtype=acc_dtype,
-            norm_every=norm_every,
-            clip_qmax=clip_qmax,
-            sym_chunk=sym_chunk,
-            k=k,
-        )
-    elif radix == 2:
+    """Dispatch phase 1: VMEM-resident radix-2, or the double-buffered
+    pipeline of stage-fused radix-4 butterflies or k-stage matrix steps."""
+    if impl == "butterfly" and radix == 2:
         _acs_phase(
             y_ref,
             pm_ref,
@@ -301,36 +209,45 @@ def _run_acs_phase(
             acc_dtype=acc_dtype,
             norm_every=norm_every,
         )
+        return
+    tile = pm_ref.shape[-1]
+    if impl == "matrix":
+
+        def step(pm, ys):
+            return matrix_step(pm, ys, code, acc_dtype, tile, k)
+
     else:
-        sym_ref, sem_ref = extra_scratch
+        k = 2
 
-        def sp_write_pair(s, words1, words2):
-            sp_write_multi(s, [words1, words2])
+        def step(pm, ys):
+            new_pm, dec1, dec2 = radix4_stage_pair(pm, ys[0], ys[1], code, acc_dtype, tile)
+            return new_pm, [dec1, dec2]
 
-        _acs_phase_r4_dbuf(
-            y_ref,
-            pl.program_id(0),
-            pm_ref,
-            sp_write,
-            sp_write_pair,
-            sym_ref,
-            sem_ref,
-            code=code,
-            n_stages=n_stages,
-            acc_dtype=acc_dtype,
-            norm_every=norm_every,
-            clip_qmax=clip_qmax,
-            sym_chunk=sym_chunk,
-        )
+    _acs_phase_dbuf(
+        y_ref,
+        pl.program_id(0),
+        pm_ref,
+        sp_write,
+        sp_write_multi,
+        *extra_scratch,
+        code=code,
+        n_stages=n_stages,
+        acc_dtype=acc_dtype,
+        norm_every=norm_every,
+        clip_qmax=clip_qmax,
+        sym_chunk=sym_chunk,
+        k=k,
+        step=step,
+    )
 
 
 def _fused_kernel(
-    y_ref,  # (T, R, TILE) symbols in VMEM (radix 2) or (T_pad, R, B) in ANY (radix 4)
+    y_ref,  # (T, R, TILE) symbols in VMEM (radix 2) or (T_pad·R, B) in ANY (dbuf)
     start_ref,  # (1, TILE) int32 traceback start state
     bits_ref,  # (n_words, TILE) int32 out: bit-packed decoded bits
     sp_ref,  # VMEM scratch (T, W, TILE) int32 survivor words
     pm_ref,  # VMEM scratch (N, TILE) acc path metrics
-    *extra_scratch,  # radix 4: (sym double buffer, DMA semaphores)
+    *extra_scratch,  # dbuf: (sym double buffer, widened tile, DMA semaphores)
     code: ConvCode,
     n_stages: int,
     decode_start: int,
@@ -412,7 +329,7 @@ def _fused_kernel(
 
 
 def _fused_prefix_kernel(
-    y_ref,  # (T, R, TILE) symbols in VMEM (radix 2) or (T_pad, R, B) in ANY (radix 4)
+    y_ref,  # (T, R, TILE) symbols in VMEM (radix 2) or (T_pad·R, B) in ANY (dbuf)
     start_ref,  # (1, TILE) int32 traceback start state
     bits_ref,  # (n_words, TILE) int32 out: bit-packed decoded bits
     sp_ref,  # VMEM scratch (n_chunks, C, W, TILE) int32 survivor words
@@ -420,7 +337,7 @@ def _fused_prefix_kernel(
     maps_ref,  # VMEM scratch (n_act, N, TILE) int32 composed chunk maps
     entry_ref,  # VMEM scratch (nc_e, TILE) int32 chunk entry states
     tbbits_ref,  # VMEM scratch (nc_e, C, TILE) int32 unpacked decoded bits
-    *extra_scratch,  # radix 4: (sym double buffer, DMA semaphores)
+    *extra_scratch,  # dbuf: (sym double buffer, widened tile, DMA semaphores)
     code: ConvCode,
     n_stages: int,
     decode_start: int,
@@ -552,7 +469,8 @@ def pbvd_fused_pallas(
     radix-2 step; decoded bits stay bit-identical to radix 2).
     ``acs_impl="matrix"`` runs the k-stage (min,+) tropical-matmul ACS on
     the same double-buffered pipeline (``sym_chunk`` rounds down to a
-    k-multiple; T mod k trailing stages run radix-2; float symbols lower to
+    multiple of lcm(k, 32), at least one; T mod k trailing stages run
+    radix-2; float symbols lower to
     the staged butterfly — see ``acs_forward_pallas``). Decoded bits stay
     bit-identical for every impl/radix/k.
     """
@@ -569,11 +487,8 @@ def pbvd_fused_pallas(
         raise ValueError(f"acs_radix must be 2 or 4, got {acs_radix}")
     if acs_impl == "matrix":
         code.validate_matrix_k(acs_k)
-    else:
-        if acs_radix == 4 and sym_chunk % 2:
-            raise ValueError(f"sym_chunk must be even, got {sym_chunk}")
-        if acs_radix == 4 and code.n_states < 4:
-            raise ValueError(f"radix-4 ACS needs K >= 3 (got K={code.K})")
+    elif acs_radix == 4 and code.n_states < 4:
+        raise ValueError(f"radix-4 ACS needs K >= 3 (got K={code.K})")
     semantic = _acc_dtype_for(y.dtype, metric_mode)
     acc_dtype = jnp.float32 if semantic == jnp.float32 else jnp.int32
     if acs_impl == "matrix" and acc_dtype == jnp.float32:
@@ -581,9 +496,6 @@ def pbvd_fused_pallas(
         # contraction is not IEEE-associative — run the butterfly body
         acs_impl, acs_radix = "butterfly", 2
     if acs_impl == "matrix":
-        # steps must not straddle symbol tiles: round the double-buffer
-        # chunk down to a k-multiple (64 → 63 for k=3)
-        sym_chunk = max(acs_k, sym_chunk - sym_chunk % acs_k)
         norm_every = norm_interval(code, metric_mode, stages_per_step=acs_k)
     else:
         norm_every = norm_interval(code, metric_mode, acs_radix)
@@ -599,12 +511,19 @@ def pbvd_fused_pallas(
         y_spec = pl.BlockSpec((T, R, LANE_TILE), lambda bt: (0, 0, bt))
     else:
         # symbols stay in HBM in their WIRE dtype (the kernel widens/clips
-        # after the VMEM load); pad T so every double-buffer DMA is
-        # statically shaped — the pad stages are never computed
+        # after the VMEM load), as flat stage-major rows. The tile is a
+        # multiple of the step depth (steps never straddle tiles) and of 32
+        # stages (every DMA row slice is aligned to the sublane tiling of
+        # any dtype up to 32 rows); T pads to a tile multiple so every DMA
+        # is statically shaped — the pad stages are never computed
+        step_k = acs_k if acs_impl == "matrix" else 2
+        align = math.lcm(step_k, _SYM_ALIGN)
+        sym_chunk = max(align, sym_chunk - sym_chunk % align)
         pad = (-T) % sym_chunk
         if pad:
             y = jnp.pad(y, ((0, pad), (0, 0), (0, 0)))
-        y_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+        y = y.reshape(-1, B)
+        y_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
 
     N = code.n_states
     W = (N + 31) // 32
@@ -658,7 +577,8 @@ def pbvd_fused_pallas(
         ]
     if dbuf:
         scratch = scratch + [
-            pltpu.VMEM((2, sym_chunk, R, LANE_TILE), y.dtype),  # double buffer
+            pltpu.VMEM((2, sym_chunk * R, LANE_TILE), y.dtype),  # double buffer
+            pltpu.VMEM((sym_chunk * R, LANE_TILE), acc_dtype),  # widened tile
             pltpu.SemaphoreType.DMA((2,)),
         ]
     packed = pl.pallas_call(

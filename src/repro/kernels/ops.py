@@ -15,8 +15,8 @@ and the paper's packed-I/O transforms. The lane axis may be a flattened
 frames × blocks packing (``FramedBlocks.frame_counts``); backends return
 exactly ``blocks.n_real_blocks`` lanes, trimming any pad lanes themselves.
 
-On CPU (this container) the Pallas kernels run in interpret mode; on TPU they
-compile natively. ``backend="ref"`` selects the pure-jnp oracle (which is
+On the CPU backend the Pallas kernels run in interpret mode; on TPU they
+compile natively (:func:`default_interpret`; any other platform is an error). ``backend="ref"`` selects the pure-jnp oracle (which is
 also the fast path on CPU and the one XLA fuses well — used by the
 benchmarks).
 """
@@ -86,34 +86,35 @@ DEFAULT_ACS_K = 2
 
 
 def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Whether Pallas kernels run in interpret mode on this process's backend.
+
+    ``True`` on the CPU backend (tests and CPU rehearsals), ``False`` on TPU.
+    Any other platform raises: the kernels are written for the TPU, and an
+    interpreter run on a platform nobody chose would hide that the chip is
+    missing while the served path still "works".
+    """
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas decode kernels run on 'tpu' (compiled) or 'cpu' (interpret "
+        f"mode, for tests); JAX's default backend is {platform!r}"
+    )
 
 
-#: Lane-axis dispatch strategies for a mesh-bound engine (DESIGN.md §12):
-#: ``"constraint"`` places the packed lanes with a NamedSharding and lets
-#: pjit partition the (collective-free) launch; ``"shard_map"`` wraps the
-#: launch in a per-shard :func:`repro.sharding.smap.shard_map` call, each
-#: shard decoding only its local lanes (pad-lane trimming then happens once,
-#: globally, after the shards are stitched — per-shard output shapes must be
-#: uniform, so the trim cannot live inside the mapped body).
-SHARD_DISPATCH = ("constraint", "shard_map")
-
-
-def check_mesh_launch(mesh, block_axes, backend: str, *, dispatch: str = "constraint") -> int:
+def check_mesh_launch(mesh, block_axes, backend: str) -> int:
     """Eagerly validate a mesh × backend decode combination; return n_shards.
 
     Every failure here is a clear pre-trace ``ValueError`` (or ``KeyError``
-    for an unknown backend) instead of a downstream pjit/shard_map shape
-    error: unknown dispatch mode, empty/duplicate ``block_axes``, axes the
-    mesh does not have, and a backend name the registry does not know.
+    for an unknown backend) instead of a downstream shard_map shape error:
+    empty/duplicate ``block_axes``, axes the mesh does not have, and a
+    backend name the registry does not know.
     Called by ``DecoderEngine`` at construction, so a bad mesh binding fails
     when the engine is built — never inside a batched launch mid-stream.
     """
     get_backend(backend)  # KeyError names the unknown backend
-    if dispatch not in SHARD_DISPATCH:
-        raise ValueError(
-            f"unknown shard dispatch {dispatch!r}; supported: {SHARD_DISPATCH}"
-        )
     axes = tuple(block_axes)
     if not axes:
         raise ValueError("block_axes must name at least one mesh axis")

@@ -156,19 +156,9 @@ def rescale_decode_engine(engine, lost_chips: int):
         return engine
     plan = plan_decode_rescale(engine.mesh, engine.block_axes, lost_chips)
     if plan is None or plan.new_chip_count < 2:
-        return DecoderEngine(
-            engine.cfg,
-            mesh=None,
-            block_axes=("data",),
-            shard_dispatch=engine.shard_dispatch,
-        )
+        return DecoderEngine(engine.cfg, mesh=None, block_axes=("data",))
     new_mesh = shrink_mesh(engine.mesh, plan.new_shape)
-    return DecoderEngine(
-        engine.cfg,
-        mesh=new_mesh,
-        block_axes=engine.block_axes,
-        shard_dispatch=engine.shard_dispatch,
-    )
+    return DecoderEngine(engine.cfg, mesh=new_mesh, block_axes=engine.block_axes)
 
 
 def reshard(tree: Any, axes_tree: Any, new_mesh: jax.sharding.Mesh, rules_map=None) -> Any:

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.sharding.smap import shard_map
 
 __all__ = ["pipeline_apply", "bubble_fraction"]
 
@@ -74,10 +73,10 @@ def pipeline_apply(
         return jax.lax.psum(acc, axis)
 
     nd = x.ndim
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis), P(*([None] * nd))),
         out_specs=P(*([None] * nd)),
-        check=False,
+        check_vma=False,
     )(stage_params, x)

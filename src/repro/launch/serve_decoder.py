@@ -117,8 +117,8 @@ class SessionPool:
     ``(mother code, D, L, backend, start_policy, metric_mode, tb_mode,
     tb_chunk, window dtype, interpret, mesh identity)``: everything that
     shapes or parameterizes the kernel launch. The mesh identity is
-    content-based — axis names, shape, device ids, the engine's
-    ``block_axes`` and shard dispatch — never ``id(mesh)``.
+    content-based — axis names, shape, device ids and the engine's
+    ``block_axes`` — never ``id(mesh)``.
     Code specs that share a mother code but differ in puncturing land in the
     same group (puncturing only affects ingest, never the launch), as do
     sessions with different payload lengths or chunk cadences.
@@ -248,8 +248,8 @@ class SessionPool:
         else:
             dt = "float32"
         # the mesh enters the key by CONTENT plus the engine's lane-axis
-        # binding: two engines on the same mesh but different block_axes (or
-        # dispatch) compile DIFFERENT launches and must not coalesce, and a
+        # binding: two engines on the same mesh but different block_axes
+        # compile DIFFERENT launches and must not coalesce, and a
         # content key — unlike the old ``id(mesh)`` — can neither split
         # equal meshes built twice nor falsely merge distinct meshes whose
         # ids collide after GC (the pool additionally pins every pooled
@@ -263,7 +263,6 @@ class SessionPool:
                 tuple((a, int(n)) for a, n in eng.mesh.shape.items()),
                 tuple(int(d.id) for d in eng.mesh.devices.flat),
                 eng.block_axes,
-                eng.shard_dispatch,
             )
         # key on the RESOLVED tb mode so an "auto" session coalesces with
         # one that spelled the backend's preferred mode out explicitly
@@ -428,7 +427,23 @@ def _latency_summary(lat_ms) -> str:
     return out
 
 
-def _serve_single(engine, spec, cfg, args) -> None:
+def _serve_status(results, quarantined: int) -> int:
+    """The process exit code of a serve mode: 1 when any stream ended
+    without decoded bits (a typed ``DecodeError`` in its result slot) or the
+    pool quarantined a stream, else 0 — a failed launch never passes as a
+    printed BER."""
+    failed = [i for i, r in enumerate(results) if not isinstance(r, np.ndarray)]
+    if not failed and not quarantined:
+        return 0
+    first = f" (stream {failed[0]}: {results[failed[0]]!r})" if failed else ""
+    print(
+        f"[serve_decoder] FAILED: {len(failed)} stream(s) without decoded "
+        f"bits{first}; {quarantined} stream(s) quarantined"
+    )
+    return 1
+
+
+def _serve_single(engine, spec, cfg, args) -> int:
     n_bits = args.chunk_bits * args.n_chunks
     payload, y = _make_stream(spec, n_bits, args.ebn0, args.seed)
     sess = engine.session()
@@ -453,9 +468,10 @@ def _serve_single(engine, spec, cfg, args) -> None:
         f"chunk latency {_latency_summary(lat_ms)}"
     )
     print(f"[serve_decoder] BER = {ber:.2e} ({int(ber * n_bits)} errors)")
+    return _serve_status([bits], 0)
 
 
-def _serve_pooled(engine, spec, cfg, args) -> None:
+def _serve_pooled(engine, spec, cfg, args) -> int:
     n_bits = args.chunk_bits * args.n_chunks
     streams = [
         _make_stream(spec, n_bits, args.ebn0, args.seed + i)
@@ -480,9 +496,8 @@ def _serve_pooled(engine, spec, cfg, args) -> None:
     dt = time.perf_counter() - t0
 
     total_bits = n_bits * args.streams
-    errors = sum(
-        int(np.sum(np.concatenate(o) != p)) for o, (p, _) in zip(outs, streams)
-    )
+    results = [np.concatenate(o) for o in outs]
+    errors = sum(int(np.sum(b != p)) for b, (p, _) in zip(results, streams))
     print(
         f"[serve_decoder] {args.streams} streams × {n_bits} bits in {dt*1e3:.0f} ms "
         f"→ aggregate {total_bits/dt/1e6:.2f} Mbps; "
@@ -494,6 +509,7 @@ def _serve_pooled(engine, spec, cfg, args) -> None:
         f"[serve_decoder] BER = {errors/total_bits:.2e} ({errors} errors "
         f"over {total_bits} bits)"
     )
+    return _serve_status(results, len(pool.quarantined))
 
 
 def _serve_async_durable(engine, spec, cfg, args) -> int:
@@ -634,7 +650,7 @@ def _serve_async_durable(engine, spec, cfg, args) -> int:
     return 0 if bad == 0 else 1
 
 
-def _serve_async(engine, spec, cfg, args) -> None:
+def _serve_async(engine, spec, cfg, args) -> int:
     """Drive the asyncio service under a Poisson arrival trace (the
     serving-layer shape: admission → paged slabs → deadline dispatch)."""
     import asyncio
@@ -673,7 +689,9 @@ def _serve_async(engine, spec, cfg, args) -> None:
     dt = time.perf_counter() - t0
     total_bits = n_bits * args.streams
     errors = sum(
-        int(np.sum(b != p)) for b, (p, _) in zip(bits, streams)
+        int(np.sum(b != p))
+        for b, (p, _) in zip(bits, streams)
+        if isinstance(b, np.ndarray)
     )
     print(
         f"[serve_decoder] async: {args.streams} streams × {n_bits} bits in "
@@ -687,6 +705,7 @@ def _serve_async(engine, spec, cfg, args) -> None:
         f"[serve_decoder] BER = {errors/total_bits:.2e} ({errors} errors "
         f"over {total_bits} bits)"
     )
+    return _serve_status(bits, report["quarantined_streams"])
 
 
 def main() -> None:
@@ -743,13 +762,6 @@ def main() -> None:
         "data=8 (CPU rehearsal: XLA_FLAGS=--xla_force_host_platform_"
         "device_count=8; multi-host: the JAX_COORDINATOR_ADDRESS/"
         "JAX_NUM_PROCESSES/JAX_PROCESS_ID env triplet, see repro.launch.mesh)",
-    )
-    ap.add_argument(
-        "--shard-dispatch",
-        default="constraint",
-        choices=["constraint", "shard_map"],
-        help="mesh dispatch path: NamedSharding placement vs explicit "
-        "per-shard shard_map (bit-identical; see DESIGN.md §12)",
     )
     ap.add_argument("--chunk-bits", type=int, default=4096, help="payload bits per chunk")
     ap.add_argument("--n-chunks", type=int, default=100)
@@ -828,12 +840,15 @@ def main() -> None:
     if args.journal_dir is not None and not args.serve_async:
         ap.error("--journal-dir requires --serve-async")
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_decode_mesh, maybe_init_distributed
 
     mesh = None
     if args.mesh:
         maybe_init_distributed()  # no-op unless the multi-host env triplet is set
         mesh = make_decode_mesh(args.mesh)
+    # after the distributed init (the helper reads the backend), before any compile
+    enable_compile_cache()
 
     spec = get_code_spec(args.code)
     cfg = PBVDConfig(
@@ -850,16 +865,13 @@ def main() -> None:
         acs_k=args.acs_k,
     )
     engine = DecoderEngine(
-        cfg,
-        mesh=mesh,
-        block_axes=None if mesh is not None else ("data",),
-        shard_dispatch=args.shard_dispatch,
+        cfg, mesh=mesh, block_axes=None if mesh is not None else ("data",)
     )
     if mesh is not None:
         print(
             f"[serve_decoder] mesh {dict(mesh.shape)} over {mesh.devices.size} "
             f"device(s); lane axis on {engine.block_axes} "
-            f"({engine.n_shards} shards, dispatch={engine.shard_dispatch})"
+            f"({engine.n_shards} shards, shard_map dispatch)"
         )
     print(
         f"[serve_decoder] {spec.name}: K={spec.code.K}, rate={spec.rate:.3f}, "
@@ -872,13 +884,14 @@ def main() -> None:
         f"in {args.n_chunks} chunks at Eb/N0={args.ebn0} dB"
     )
     if args.serve_async and args.journal_dir is not None:
-        raise SystemExit(_serve_async_durable(engine, spec, cfg, args))
+        serve = _serve_async_durable
     elif args.serve_async:
-        _serve_async(engine, spec, cfg, args)
+        serve = _serve_async
     elif args.streams > 1:
-        _serve_pooled(engine, spec, cfg, args)
+        serve = _serve_pooled
     else:
-        _serve_single(engine, spec, cfg, args)
+        serve = _serve_single
+    raise SystemExit(serve(engine, spec, cfg, args))
 
 
 if __name__ == "__main__":

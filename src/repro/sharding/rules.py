@@ -116,23 +116,6 @@ def current_rules() -> LogicalRules | None:
     return getattr(_local, "rules", None)
 
 
-def _mesh_context(mesh: Mesh):
-    """Enter ``mesh`` as the ambient mesh, across JAX API generations.
-
-    Newer JAX exposes ``jax.set_mesh`` / ``jax.sharding.use_mesh`` context
-    managers; older releases (like the one pinned here) only support the mesh
-    itself as a context manager. Try them in order of recency.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    for name in ("use_mesh", "set_mesh"):
-        fn = getattr(jax.sharding, name, None)
-        if fn is not None:
-            return fn(mesh)
-    return mesh  # legacy: Mesh is itself a context manager
-
-
 @contextlib.contextmanager
 def axis_rules(mesh: Mesh, rules: Mapping[str, str | tuple[str, ...] | None] | None = None):
     """Activate a logical→mesh rule-set (and the mesh) for the enclosed code."""
@@ -141,7 +124,7 @@ def axis_rules(mesh: Mesh, rules: Mapping[str, str | tuple[str, ...] | None] | N
         rules = DEFAULT_RULES if "pod" in mesh.axis_names else SINGLE_POD_RULES
     _local.rules = LogicalRules(mesh, rules)
     try:
-        with _mesh_context(mesh):
+        with jax.set_mesh(mesh):
             yield _local.rules
     finally:
         _local.rules = prev

@@ -1,21 +1,11 @@
-"""shard_map compatibility shim (API moved between JAX versions), plus the
-lane-axis dispatch helper the mesh-bound decode path uses."""
+"""The lane-axis ``shard_map`` dispatch the mesh-bound decode path uses."""
 
 from __future__ import annotations
 
-__all__ = ["shard_map", "lane_shard_map"]
+import jax
+from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6: top-level, check_vma kwarg
-    from jax import shard_map as _sm  # type: ignore[attr-defined]
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check)
-
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _sm_old
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-        return _sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check)
+__all__ = ["lane_shard_map"]
 
 
 def lane_shard_map(f, *, mesh, axes, in_rank: int, out_rank: int):
@@ -27,8 +17,8 @@ def lane_shard_map(f, *, mesh, axes, in_rank: int, out_rank: int):
     lane axis; ``in_rank``/``out_rank`` are the operand/result ranks (the
     leading axes are replicated).
     """
-    from jax.sharding import PartitionSpec as P
-
     in_specs = P(*([None] * (in_rank - 1) + [tuple(axes)]))
     out_specs = P(*([None] * (out_rank - 1) + [tuple(axes)]))
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )

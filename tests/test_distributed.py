@@ -161,7 +161,7 @@ def test_dryrun_smoke_tiny_mesh():
 def test_mesh_decode_parity_matrix():
     """The acceptance matrix: on 8 host devices, ``decode`` and
     ``decode_batch`` are bit-identical with and without a ``data=8`` mesh,
-    across backends × metric modes × both shard dispatches, for a ragged
+    across backends × metric modes under shard_map dispatch, for a ragged
     fleet whose block count does not divide the shard count."""
     _run("""
         import jax, jax.numpy as jnp, numpy as np
@@ -194,19 +194,18 @@ def test_mesh_decode_parity_matrix():
             base = DecoderEngine(cfg)
             refs = [np.asarray(b) for b in base.decode_batch(ys, lens)]
             ref1 = np.asarray(base.decode(ys[1], lens[1]))
-            for dispatch in ("constraint", "shard_map"):
-                tag = (backend, mm, dispatch)
-                eng = DecoderEngine(cfg, mesh=mesh, shard_dispatch=dispatch)
-                assert eng.n_shards == 8, tag
-                for r, o in zip(refs, eng.decode_batch(ys, lens)):
-                    assert np.array_equal(r, np.asarray(o)), tag
-                assert np.array_equal(ref1, np.asarray(eng.decode(ys[1], lens[1]))), tag
-                print("ok", *tag)
+            tag = (backend, mm)
+            eng = DecoderEngine(cfg, mesh=mesh)
+            assert eng.n_shards == 8, tag
+            for r, o in zip(refs, eng.decode_batch(ys, lens)):
+                assert np.array_equal(r, np.asarray(o)), tag
+            assert np.array_equal(ref1, np.asarray(eng.decode(ys[1], lens[1]))), tag
+            print("ok", *tag)
     """, timeout=1800)
 
 
 def test_mesh_pooled_step_parity_and_streaming():
-    """Pooled sessions on a sharded engine (both dispatches, mixed with a
+    """Pooled sessions on sharded engines (two mesh sizes, mixed with a
     meshless engine in the same pool) stream bit-identically to the solo
     unsharded decode, under a ragged chunk cadence."""
     _run("""
@@ -234,7 +233,7 @@ def test_mesh_pooled_step_parity_and_streaming():
         engines = [
             DecoderEngine(cfg),
             DecoderEngine(cfg, mesh=mesh),
-            DecoderEngine(cfg, mesh=mesh, shard_dispatch="shard_map"),
+            DecoderEngine(cfg, mesh=make_decode_mesh("data=4")),
         ]
         pool = SessionPool()
         handles = [pool.open(e) for e in engines]
@@ -253,7 +252,7 @@ def test_mesh_pooled_step_parity_and_streaming():
             outs[i].append(h.finish(n))
             got = np.concatenate(outs[i])
             assert np.array_equal(got, ref), f"handle {i} diverged"
-        # meshless / constraint / shard_map are three distinct launch groups
+        # meshless / data=8 / data=4 are three distinct launch groups
         assert len({pool._group_key(h._session) for h in handles}) == 3
         print("ok", pool.launches)
     """)

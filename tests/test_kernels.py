@@ -598,3 +598,20 @@ def test_narrow_pm_saturates_out_of_budget_symbols():
     sp_ref, _ = acs_forward_ref(jnp.clip(y8, -qm, qm), code, metric_mode="f32")
     assert jnp.array_equal(sp_i8, sp_ref)
     assert int(jnp.max(jnp.abs(pm_i8))) <= 127  # no wrap
+
+
+@pytest.mark.parametrize(
+    "platform, expected", [("cpu", True), ("tpu", False), ("gpu", None), ("rocm", None)]
+)
+def test_default_interpret_only_on_cpu(monkeypatch, platform, expected):
+    """Interpret mode is the CPU's alone: the TPU compiles the kernels, and a
+    platform the kernels were not written for is an error, not a silent
+    interpreter run."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: platform)
+    if expected is None:
+        with pytest.raises(RuntimeError, match=platform):
+            ops.default_interpret()
+    else:
+        assert ops.default_interpret() is expected

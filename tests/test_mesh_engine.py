@@ -2,7 +2,7 @@
 
 The real N-chip behavior is exercised by ``tests/test_distributed.py``
 (subprocess, 8 forced host devices, ``slow``); this module keeps the mesh
-code paths — shard-aware lane budgeting, both dispatch modes, eager
+code paths — shard-aware lane budgeting, the shard_map dispatch, eager
 validation, pool grouping by mesh *content* — inside the tier-1 gate with
 trivial single-device meshes (the sharding is degenerate, the code path is
 not).
@@ -66,12 +66,12 @@ def test_lane_budget_folds_shard_rounding_into_one_bounded_pad():
 # ---------------------------------------------------------------------------
 # mesh-bound decode parity (degenerate 1-device mesh, real code path)
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("dispatch", ["constraint", "shard_map"])
-def test_mesh_engine_matches_unsharded_both_dispatches(dispatch):
+@pytest.mark.parametrize("metric_mode", ["f32", "i8"])
+def test_mesh_engine_matches_unsharded(metric_mode):
     spec = get_code_spec("ccsds")
-    cfg = PBVDConfig(spec=spec, D=64, L=16, q=8, backend="ref")
+    cfg = PBVDConfig(spec=spec, D=64, L=16, q=8, backend="ref", metric_mode=metric_mode)
     base = DecoderEngine(cfg)
-    eng = DecoderEngine(cfg, mesh=_mesh1(), shard_dispatch=dispatch)
+    eng = DecoderEngine(cfg, mesh=_mesh1())
     assert eng.n_shards == 1 and eng.block_axes == ("data",)
     lens = [96, 190, 96]
     ys = [_tx("ccsds", n, 40 + i) for i, n in enumerate(lens)]
@@ -89,16 +89,13 @@ def test_mesh_engine_matches_unsharded_both_dispatches(dispatch):
     np.testing.assert_array_equal(got, np.asarray(base.decode(ys[1], lens[1])))
 
 
-def test_decode_stream_sharded_dispatch_passthrough():
+def test_decode_stream_sharded_passthrough():
     spec = get_code_spec("ccsds")
     cfg = PBVDConfig(spec=spec, D=64, L=16, q=8, backend="ref")
     y = _tx("ccsds", 128, 3)
     ref = np.asarray(DecoderEngine(cfg).decode(y, 128))
-    for dispatch in ("constraint", "shard_map"):
-        out = decode_stream_sharded(
-            y, 128, cfg, _mesh1(), block_axes=None, shard_dispatch=dispatch
-        )
-        np.testing.assert_array_equal(ref, np.asarray(out))
+    out = decode_stream_sharded(y, 128, cfg, _mesh1(), block_axes=None)
+    np.testing.assert_array_equal(ref, np.asarray(out))
 
 
 # ---------------------------------------------------------------------------
@@ -114,8 +111,6 @@ def test_check_mesh_launch_rejects_bad_bindings_eagerly():
         check_mesh_launch(mesh, ("data", "data"), "ref")
     with pytest.raises(ValueError, match="at least one"):
         check_mesh_launch(mesh, (), "ref")
-    with pytest.raises(ValueError, match="shard dispatch"):
-        check_mesh_launch(mesh, ("data",), "ref", dispatch="pjit")
     with pytest.raises(KeyError):
         check_mesh_launch(mesh, ("data",), "no_such_backend")
     # the engine runs the same check at CONSTRUCTION, not at first decode
@@ -202,8 +197,8 @@ def test_session_pool_coalesces_equal_content_meshes_and_pins_them():
     hb.feed(y)
     pool.step()
     assert pool.launches == 1
-    # dispatch is part of the identity: a shard_map engine splits the group
-    eng_c = DecoderEngine(cfg, mesh=_mesh1(), shard_dispatch="shard_map")
+    # the mesh is part of the identity: a meshless engine splits the group
+    eng_c = DecoderEngine(cfg)
     ha2, hc = pool.open(eng_a), pool.open(eng_c)
     ha2.feed(y)
     hc.feed(y)
@@ -212,6 +207,7 @@ def test_session_pool_coalesces_equal_content_meshes_and_pins_them():
     ref = np.asarray(DecoderEngine(cfg).decode(jnp.asarray(y), 256))
     for h in (ha, hb, ha2, hc):
         np.testing.assert_array_equal(np.concatenate([h.take(), h.finish(256)]), ref)
+    assert len(pool._mesh_refs) == 3  # the meshless member pins nothing
     pool.close(ha)
     pool.close(hc)
     assert len(pool._mesh_refs) == 2  # hb's and ha2's meshes still pinned

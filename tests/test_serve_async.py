@@ -37,7 +37,7 @@ from repro.launch.serve_async import (
     DeadlineBatcher,
     run_poisson_trace,
 )
-from repro.launch.serve_decoder import SessionPool, _latency_summary
+from repro.launch.serve_decoder import SessionPool, _latency_summary, _serve_status
 from repro.launch.slab import PagedSessionStore, SlabExhausted, SymbolSlab
 
 GEOM = dict(D=64, L=16, q=8)
@@ -531,3 +531,17 @@ def test_async_service_64_stream_poisson_bit_exact():
     assert slab.pages_in_use == 0  # every stream's pages returned
     # the dispatcher coalesced: far fewer pool steps than chunks
     assert report["dispatches"] < report["chunks"]
+
+
+def test_serve_status_fails_on_any_stream_failure():
+    """A serve mode exits non-zero when a stream ended in a typed
+    DecodeError (or any non-array result) or the pool quarantined one —
+    a printed BER never stands in for a failed launch."""
+    from repro.launch.faults import DispatchError, StreamError
+
+    ok = [np.zeros(8, np.int32), np.ones(8, np.int32)]
+    assert _serve_status(ok, 0) == 0
+    assert _serve_status(ok, 1) == 1
+    assert _serve_status([ok[0], StreamError("poisoned")], 0) == 1
+    assert _serve_status([DispatchError("launch refused"), ok[1]], 0) == 1
+    assert _serve_status([ok[0], None], 0) == 1
