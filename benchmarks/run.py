@@ -33,8 +33,8 @@ four into one artifact, then gates it with tools/bench_compare.py:
     python benchmarks/run.py --acs-radix --out BENCH_pr.json --smoke
     python benchmarks/run.py --acs-impl --out BENCH_pr.json --smoke
 
-Roofline tables (assignment §Roofline) are produced by
-``python -m repro.launch.roofline`` from the dry-run reports.
+Roofline shares on the chip come from the on-chip benchmark (``bench/run.py``
+with ``--trace 1``, reduced by ``bench/roofline.py``).
 """
 
 from __future__ import annotations

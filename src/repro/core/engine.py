@@ -37,7 +37,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.ops import check_mesh_launch, pbvd_decode_blocks
+from repro.kernels.ops import check_mesh_launch, launched_lanes, pbvd_decode_blocks
 from repro.launch.faults import SymbolError, check_finite_symbols
 from .codespec import CodeSpec
 
@@ -114,6 +114,24 @@ class ArraySessionStore:
 def _pow2_at_least(n: int) -> int:
     """Smallest power of two ≥ n (the shared jit shape budget)."""
     return 1 << max(0, n - 1).bit_length()
+
+
+def _covered_lane_stages(lo: int, k: int, D: int, T: int, a: int, b: int) -> int:
+    """Stages of ``[a, b)`` summed over the ``k`` lanes ``[lo + jD, lo + jD + T)``.
+
+    Lanes wholly inside ``[a, b)`` count ``T`` each; only the lanes cut by
+    ``a`` or ``b`` (at most about ``2 + (T - D) / D`` of them at either end)
+    are measured one by one, so the cost does not grow with ``k``.
+    """
+    if k <= 0 or b <= a:
+        return 0
+    j0 = min(k, max(0, -(-(a - lo) // D)))  # first lane starting at or after a
+    j1 = max(j0, min(k, (b - T - lo) // D + 1))  # first lane ending after b
+    total = (j1 - j0) * T
+    for j in (*range(j0), *range(j1, k)):
+        s = lo + j * D
+        total += max(0, min(s + T, b) - max(s, a))
+    return total
 
 
 class DecoderEngine:
@@ -263,6 +281,13 @@ class DecoderEngine:
         budget = _pow2_at_least(n)
         s = self.n_shards
         return budget * s // math.gcd(budget, s)
+
+    def _launched_lanes(self, n: int) -> int:
+        """Lanes the kernels run for ``n`` real lanes: the lane budget, each
+        shard's share rounded up to the backend's lane tile by the same
+        :func:`~repro.kernels.ops.launched_lanes` the backend pads with."""
+        per_shard = self._lane_budget(n) // self.n_shards
+        return self.n_shards * launched_lanes(self.cfg.backend, per_shard)
 
     def _pad_lanes(self, blocks):
         """Pad the lane axis to :meth:`_lane_budget` with zero-symbol blocks."""
@@ -585,13 +610,17 @@ class DecoderSession:
         solo and pooled launches share that mechanism, so pad lanes are
         identical zero-symbol blocks on both paths.
         """
+        return self._frame_device(self._frame_host(b1), b1 - self._blocks_done)
+
+    def _frame_host(self, b1: int) -> np.ndarray:
+        """The host window of blocks [blocks_done, b1): global stages
+        [b0·D − L, b1·D + L), zero where the stream has no symbol, in the
+        dtype that goes to the device."""
         b0 = self._blocks_done
-        k = b1 - b0
         cfg = self.cfg
         D, L, R = cfg.D, cfg.L, self.spec.code.R
-        T = D + 2 * L
         lo = b0 * D - L  # global first stage of the combined window
-        hi_pad = (b0 + k) * D + L  # exclusive global end incl. padding
+        hi_pad = b1 * D + L  # exclusive global end incl. padding
         left_pad = max(0, -lo)  # only the very first block reaches stage -L
         s0 = max(lo, 0) - self._base
         need = hi_pad - max(lo, 0)
@@ -604,15 +633,29 @@ class DecoderSession:
         if right_pad > 0:
             parts.append(np.zeros((right_pad, R), np.float32))
         w = np.concatenate(parts) if len(parts) > 1 else parts[0]
-
         if self._int_dtype is not None:  # pre-quantized stream: exact passthrough
-            y = jnp.asarray(w.astype(self._int_dtype))
-        else:
-            y = jnp.asarray(w)
-            if cfg.effective_q is not None:
-                y = cfg.quantize(y)
-        idx = np.arange(T)[:, None] + np.arange(k)[None, :] * D
+            return w.astype(self._int_dtype)
+        return w
+
+    def _frame_device(self, w: np.ndarray, k: int) -> jnp.ndarray:
+        """Copy a :meth:`_frame_host` window of ``k`` blocks to the device,
+        quantize it, and gather it into (T, R, k) lanes."""
+        cfg = self.cfg
+        T = cfg.D + 2 * cfg.L
+        y = jnp.asarray(w)
+        if self._int_dtype is None and cfg.effective_q is not None:
+            y = cfg.quantize(y)
+        idx = np.arange(T)[:, None] + np.arange(k)[None, :] * cfg.D
         return jnp.transpose(y[idx], (0, 2, 1))  # (T, R, k)
+
+    def _received_lane_stages(self, b1: int) -> int:
+        """Lane-stages of blocks [blocks_done, b1) that carry a received
+        stage, not the zero padding of :meth:`_frame_host`."""
+        D, L = self.cfg.D, self.cfg.L
+        b0 = self._blocks_done
+        return _covered_lane_stages(
+            b0 * D - L, b1 - b0, D, D + 2 * L, 0, self._base + len(self._store)
+        )
 
     def _commit(self, b1: int) -> None:
         """Advance past blocks [blocks_done, b1); trim the consumed buffer."""

@@ -610,5 +610,6 @@ def acs_forward_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((N, LANE_TILE), acc_dtype)],
         interpret=interpret,
+        name="pbvd_acs_forward",
     )(*operands)
     return sp, pm

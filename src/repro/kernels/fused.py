@@ -592,5 +592,6 @@ def pbvd_fused_pallas(
         out_shape=jax.ShapeDtypeStruct((n_words, B), jnp.int32),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="pbvd_fused",
     )(y, start_state.reshape(1, B).astype(jnp.int32))
     return packed

@@ -49,6 +49,7 @@ from .registry import (
     backend_tb_modes,
     get_backend,
     knob_error,
+    launched_lanes,
     register_backend,
     resolve_tb_mode,
 )
@@ -75,6 +76,7 @@ __all__ = [
     "backend_acs_radix",
     "backend_acs_impl",
     "backend_preferred_tb_mode",
+    "launched_lanes",
     "resolve_tb_mode",
     "knob_error",
 ]
@@ -134,8 +136,11 @@ def check_mesh_launch(mesh, block_axes, backend: str) -> int:
 
 
 def _pad_axis(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
-    n = x.shape[axis]
-    pad = (-n) % multiple
+    return _pad_to(x, axis, -(-x.shape[axis] // multiple) * multiple)
+
+
+def _pad_to(x: jnp.ndarray, axis: int, size: int) -> jnp.ndarray:
+    pad = size - x.shape[axis]
     if not pad:
         return x
     widths = [(0, 0)] * x.ndim
@@ -201,6 +206,7 @@ def _decode_ref(
     preferred_tb_mode="serial",
     acs_radix=(2, 4),
     acs_impl=("butterfly", "matrix"),
+    lane_tile=LANE_TILE,
 )
 def _decode_pallas(
     blocks: FramedBlocks,
@@ -223,7 +229,7 @@ def _decode_pallas(
         # the chunk down to a k-multiple (64 → 63 for k=3); stage padding
         # below then keeps T a chunk multiple as before
         stage_chunk = max(acs_k, stage_chunk - stage_chunk % acs_k)
-    y = _pad_axis(blocks.y, 2, LANE_TILE)  # lane padding
+    y = _pad_to(blocks.y, 2, launched_lanes("pallas", blocks.y.shape[2]))
     y = _pad_axis(y, 0, stage_chunk)  # stage padding (end; BM-neutral zeros)
     Bp = y.shape[2]
 
@@ -279,6 +285,7 @@ def _decode_pallas(
     # (see the pallas registration note; same TPU re-measure applies here)
     acs_radix=(2, 4),
     acs_impl=("butterfly", "matrix"),
+    lane_tile=LANE_TILE,
 )
 def _decode_fused(
     blocks: FramedBlocks,
@@ -305,7 +312,7 @@ def _decode_fused(
             "fused backend tracebacks from state 0 (start_policies=('zero',))"
         )
     nd = -(-blocks.n_decode // 32) * 32  # kernel emits 32-bit words
-    y = _pad_axis(blocks.y, 2, LANE_TILE)
+    y = _pad_to(blocks.y, 2, launched_lanes("fused", blocks.y.shape[2]))
     packed = pbvd_fused_pallas(
         y,
         code,

@@ -55,6 +55,7 @@ __all__ = [
     "backend_acs_radix",
     "backend_acs_impl",
     "backend_preferred_tb_mode",
+    "launched_lanes",
     "resolve_tb_mode",
     "knob_error",
 ]
@@ -352,6 +353,7 @@ def register_backend(
     preferred_tb_mode: str = "serial",
     acs_radix: tuple[int, ...] = (2,),
     acs_impl: tuple[str, ...] = ("butterfly",),
+    lane_tile: int = 1,
 ) -> Callable[[DecodeBackend], DecodeBackend]:
     """Decorator: register a decode backend under ``name``.
 
@@ -374,7 +376,13 @@ def register_backend(
     traceback ignores ``tb_chunk`` (e.g. a full-depth associative scan): the
     dispatcher then normalizes the knob out of the jit cache key, and the
     benchmarks collapse the chunk sweep dimension.
+
+    ``lane_tile`` declares the lane multiple the backend pads a launch to
+    (its kernels' lane tile); :func:`launched_lanes` is that padding, which
+    the backend applies and the serving layer counts.
     """
+    if lane_tile < 1:
+        raise ValueError(f"lane_tile must be >= 1, got {lane_tile}")
     unknown = set(metric_modes) - METRIC_MODES.keys()
     if unknown:
         raise ValueError(f"unknown metric modes {sorted(unknown)}")
@@ -404,6 +412,7 @@ def register_backend(
         fn.preferred_tb_mode = str(preferred_tb_mode)  # type: ignore[attr-defined]
         fn.acs_radix = tuple(acs_radix)  # type: ignore[attr-defined]
         fn.acs_impl = tuple(acs_impl)  # type: ignore[attr-defined]
+        fn.lane_tile = int(lane_tile)  # type: ignore[attr-defined]
         return fn
 
     return deco
@@ -451,6 +460,13 @@ def backend_acs_impl(name: str) -> tuple[str, ...]:
 def backend_preferred_tb_mode(name: str) -> str:
     """The named backend's declared measured-fastest traceback mode."""
     return getattr(get_backend(name), "preferred_tb_mode", "serial")
+
+
+def launched_lanes(name: str, n: int) -> int:
+    """Lanes a launch of ``n`` lanes runs on the named backend: ``n`` rounded
+    up to the backend's declared lane tile."""
+    tile = getattr(get_backend(name), "lane_tile", 1)
+    return -(-n // tile) * tile
 
 
 def resolve_tb_mode(name: str, tb_mode: str) -> str:

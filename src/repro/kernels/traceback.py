@@ -142,6 +142,7 @@ def traceback_pallas(
         out_specs=pl.BlockSpec((n_decode, LANE_TILE), lambda bt: (0, bt)),
         out_shape=jax.ShapeDtypeStruct((n_decode, B), jnp.int32),
         interpret=interpret,
+        name="pbvd_traceback",
     )(sp, start_state.reshape(1, B).astype(jnp.int32))
     return bits
 
@@ -340,6 +341,7 @@ def traceback_prefix_pallas(
             pltpu.VMEM((nc_e, LANE_TILE), jnp.int32),
         ],
         interpret=interpret,
+        name="pbvd_traceback_prefix",
     )(spr, start_state.reshape(1, B).astype(jnp.int32))
     # chunk-major (nc_e, C, B) → stage-major rows of the decode region
     ds_local = (decode_start + P) - c_lo * C

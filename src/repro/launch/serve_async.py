@@ -75,6 +75,7 @@ from repro.launch.faults import (
 from repro.launch.journal import ChunkJournal, IntegritySentinel
 from repro.launch.serve_decoder import SessionPool
 from repro.launch.slab import SlabExhausted, SymbolSlab
+from repro.launch.spans import span
 
 __all__ = [
     "Backpressure",
@@ -341,6 +342,10 @@ class AsyncDecodeService:
         self._task: asyncio.Task | None = None
         self._closing = False
         self.dispatches = 0
+        # admitted chunks, and the seconds (on ``clock``) they spent parked
+        # behind the pending-block cap or slab pages before admission
+        self.admits = 0
+        self.admit_wait_s = 0.0
         self._t_first: float | None = None
         self._t_last: float | None = None
         self._bits_delivered = 0
@@ -413,7 +418,7 @@ class AsyncDecodeService:
         store = self._slab.open_store() if self._slab is not None else None
         handle = self._pool.open(engine, interpret=interpret, store=store)
         stream = AsyncStream(self, handle)
-        stream.sid = self._next_sid
+        stream.sid = handle.sid = self._next_sid
         self._next_sid += 1
         self._streams.append(stream)
         self._by_handle[handle] = stream
@@ -451,6 +456,12 @@ class AsyncDecodeService:
         return True
 
     def _dispatch(self) -> None:
+        """One coalesced step (:meth:`_dispatch_step`) inside a
+        ``pbvd.dispatch`` span."""
+        with span("pbvd.dispatch", n=self.dispatches + 1, members=len(self._pool)):
+            self._dispatch_step()
+
+    def _dispatch_step(self) -> None:
         """One coalesced step under the failure model (DESIGN.md §14).
 
         Success resets the retry state. A transient failure arms a bounded
@@ -793,7 +804,7 @@ class AsyncDecodeService:
         handle._queue.extend(np.asarray(a) for a in snap["queue"])
         handle.bits_emitted = int(snap["handle_bits"])
         stream = AsyncStream(self, handle)
-        stream.sid = sid
+        stream.sid = handle.sid = sid
         # the client's position restarts at the checkpoint's ack watermark;
         # replayed ack records past it turn into suppression below
         stream.bits_taken = stream.acked_bits = int(snap["acked"])
@@ -893,11 +904,13 @@ class AsyncDecodeService:
             self._count_error(err)
             self._fail_stream(stream, err)
             raise err
+        chunk = np.asarray(chunk)
         t0 = self._clock()  # the shed deadline spans the WHOLE admission
+        parked = 0.0
         while True:
             self._check_live(stream)
             if self._pool.pending_blocks() >= self.max_pending_blocks:
-                await self._wait_for_space("pending-block cap", t0)
+                parked += await self._wait_for_space("pending-block cap", t0)
                 continue
             try:
                 if self._injector is not None and self._injector.fire("slab"):
@@ -907,7 +920,9 @@ class AsyncDecodeService:
                 # session ingest is atomic w.r.t. slab exhaustion: page
                 # capacity is reserved before any symbol is written, so a
                 # failed admit can simply retry after the next dispatch
-                stream._handle.feed(chunk)
+                stages = int(chunk.shape[0]) if chunk.ndim else 0
+                with span("pbvd.ingest", sid=stream.sid, stages=stages):
+                    stream._handle.feed(chunk)
             except SlabExhausted as exc:
                 self._count_error(exc)
                 if self._pool.pending_blocks() <= 0:
@@ -915,7 +930,7 @@ class AsyncDecodeService:
                         continue  # synthetic fault, nothing to free: re-admit
                     # nothing a dispatch could free — the chunk cannot fit
                     raise
-                await self._wait_for_space("slab pages", t0)
+                parked += await self._wait_for_space("slab pages", t0)
                 continue
             except StreamError as err:
                 # engine-boundary validation (non-finite or shape-invalid
@@ -932,11 +947,13 @@ class AsyncDecodeService:
         # (chunks_admitted, derived from this record) re-sends it.
         if self._journal is not None and not self._recovering:
             try:
-                self._journal.append("admit", stream.sid, np.asarray(chunk))
+                self._journal.append("admit", stream.sid, chunk)
             except OSError as exc:  # durability broken → the service is dead
                 self._fail_service(exc)
                 raise self._failure from exc
         stream.chunks_admitted += 1
+        self.admits += 1
+        self.admit_wait_s += parked
         now = self._clock()
         if self._t_first is None:
             self._t_first = now
@@ -944,7 +961,10 @@ class AsyncDecodeService:
         self._batcher.note_feed()
         self._work.set()
 
-    async def _wait_for_space(self, why: str, t0: float) -> None:
+    async def _wait_for_space(self, why: str, t0: float) -> float:
+        """Park the sender until a dispatch or finish frees capacity; return
+        the seconds parked, on the service clock. Raises instead where the
+        service does not block or the shed deadline has passed."""
         if not self.block_on_backpressure:
             exc = Backpressure(f"admission refused: {why} exhausted")
             self._count_error(exc)
@@ -962,9 +982,10 @@ class AsyncDecodeService:
             raise exc
         self._space.clear()
         self._work.set()  # ensure the dispatcher wakes to make progress
+        t = self._clock()
         if self.shed_deadline_ms is None:
             await self._space.wait()
-            return
+            return self._clock() - t
         # real-time backstop so a stalled dispatcher cannot outlive the shed
         # deadline; the deterministic check above (injected clock) decides
         remaining = self.shed_deadline_ms / 1e3 - (self._clock() - t0)
@@ -972,6 +993,7 @@ class AsyncDecodeService:
             await asyncio.wait_for(self._space.wait(), max(0.0, remaining))
         except asyncio.TimeoutError:
             pass
+        return self._clock() - t
 
     async def _finish(self, stream: AsyncStream, n_bits: int | None) -> np.ndarray:
         self._check_live(stream)
@@ -992,33 +1014,42 @@ class AsyncDecodeService:
                 )
         attempt = 0
         while True:
-            try:
-                bits = stream._handle.finish(n_bits)  # take() fold + flush plan
-                break
-            except StreamError as err:
-                # the stream's own flush launch is what fails: quarantine it
-                self._count_error(err)
-                self._fail_stream(stream, err)
-                raise err from None
-            except CapacityError:
-                raise  # a flush never allocates; surface allocator bugs loudly
-            except MeshLost as exc:
-                self._count_error(exc)
-                self._handle_mesh_loss(exc)
-                self.retries += 1
-                continue  # flush replays on the rebuilt engine, bit-exact
-            except Exception as exc:  # noqa: BLE001 - transient flush failure
-                self._count_error(exc)
-                if attempt >= self.retry.max_retries:
-                    err = StreamError(
-                        f"stream flush failed after {attempt} retries ({exc!r})"
-                    )
-                    err.__cause__ = exc
+            with span("pbvd.finish", sid=stream.sid):
+                try:
+                    bits = stream._handle.finish(n_bits)  # take() fold + flush plan
+                except StreamError as err:
+                    # the stream's own flush launch is what fails: quarantine it
+                    self._count_error(err)
                     self._fail_stream(stream, err)
-                    raise err from exc
-                await asyncio.sleep(self.retry.delay_s(attempt))
-                attempt += 1
-                self.retries += 1
+                    raise err from None
+                except CapacityError:
+                    raise  # a flush never allocates; surface allocator bugs loudly
+                except MeshLost as exc:
+                    self._count_error(exc)
+                    self._handle_mesh_loss(exc)
+                    self.retries += 1
+                    continue  # flush replays on the rebuilt engine, bit-exact
+                except Exception as exc:  # noqa: BLE001 - transient flush failure
+                    self._count_error(exc)
+                    if attempt >= self.retry.max_retries:
+                        err = StreamError(
+                            f"stream flush failed after {attempt} retries ({exc!r})"
+                        )
+                        err.__cause__ = exc
+                        self._fail_stream(stream, err)
+                        raise err from exc
+                else:
+                    return self._finish_close(stream, bits, before, cap)
+            # the backoff waits outside the span: no span is open across an await
+            await asyncio.sleep(self.retry.delay_s(attempt))
+            attempt += 1
+            self.retries += 1
+
+    def _finish_close(
+        self, stream: AsyncStream, bits: np.ndarray, before: int, cap
+    ) -> np.ndarray:
+        """Screen a flushed stream's tail, hand its bits over and release it:
+        pool exit, slab pages back to the free-list, the service's books."""
         if cap is not None:
             tail_len = stream._handle.bits_emitted - before
             tail = bits[len(bits) - tail_len :] if tail_len else bits[:0]
@@ -1070,6 +1101,14 @@ class AsyncDecodeService:
             chunks=int(lat.size),
             dispatches=self.dispatches,
             launches=self._pool.launches,
+            lanes_real=self._pool.lanes_real,
+            lanes_launched=self._pool.lanes_launched,
+            stages_real=self._pool.stages_real,
+            stages_launched=self._pool.stages_launched,
+            h2d_bytes=self._pool.h2d_bytes,
+            d2h_bytes=self._pool.d2h_bytes,
+            admits=self.admits,
+            admit_wait_s=self.admit_wait_s,
             bits_delivered=self._bits_delivered,
             span_s=span,
             sustained_mbps=(

@@ -38,6 +38,7 @@ from repro.core.encoder import encode_jax, terminate
 from repro.core.engine import DecoderEngine, DecoderSession
 from repro.core.pbvd import PBVDConfig
 from repro.launch.faults import StreamError
+from repro.launch.spans import span
 from repro.kernels.ops import (
     DEFAULT_TB_CHUNK,
     available_backends,
@@ -61,6 +62,7 @@ class PooledSession:
         self._session = session
         self._queue: list[np.ndarray] = []
         self.bits_emitted = 0
+        self.sid: int | None = None  # the serving layer's stream id, for spans
 
     def feed(self, chunk) -> None:
         """Buffer a chunk of received symbols (same wire formats as
@@ -143,6 +145,16 @@ class SessionPool:
         # member, dropping or double-releasing the wrong mesh pin
         self._mesh_refs: dict[PooledSession, object] = {}
         self.launches = 0  # batched launches issued (for reporting/tests)
+        # what the launches carried: real lanes and the lanes the kernels
+        # ran (pow2/shard budget and lane tile), lane-stages that hold a
+        # received stage and all T = D + 2L of each real lane, bytes of the
+        # framed host windows sent to the device and of the bits copied back
+        self.lanes_real = 0
+        self.lanes_launched = 0
+        self.stages_real = 0
+        self.stages_launched = 0
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
         # fault-tolerance hooks (DESIGN.md §14): ``fault_hook(entries,
         # isolating)`` is consulted before every launch (the injection point
         # for repro.launch.faults.FaultInjector); quarantined members land in
@@ -307,27 +319,46 @@ class SessionPool:
         """
         if self.fault_hook is not None:
             self.fault_hook(entries, isolating)
-        frames, counts = [], []
-        for ps, b1 in entries:
-            s = ps._session
-            frames.append(s._frame_ready(b1))
-            counts.append(b1 - s._blocks_done)
-        packed = jnp.concatenate(frames, axis=2) if len(frames) > 1 else frames[0]
         lead = entries[0][0]._session
-        # the lead engine's shard-aware budget (pow2 rounded once to the
-        # mesh shard count) — identical for every member, since the group
-        # key includes the full mesh identity + block_axes
-        packed = lead.engine._pad_lanes(packed)
-        bits = lead.engine._decode_blocks(packed, tuple(counts), lead._interpret)
-        self.launches += 1
-        outs, lo = [], 0
-        for (ps, b1), k in zip(entries, counts):
-            sub = np.asarray(
-                jnp.transpose(bits[:, lo : lo + k]), dtype=np.int32
-            ).reshape(-1)
-            ps._session._commit(b1)
-            outs.append(sub)
-            lo += k
+        eng = lead.engine
+        counts = [b1 - ps._session._blocks_done for ps, b1 in entries]
+        n_real = sum(counts)
+        lanes = eng._launched_lanes(n_real)
+        args = dict(members=len(entries), lanes_real=n_real, lanes=lanes)
+        if len(entries) == 1 and entries[0][0].sid is not None:
+            args["sid"] = entries[0][0].sid
+        with span("pbvd.launch", **args):
+            with span("pbvd.frame"):
+                frames, h2d, stages = [], 0, 0
+                for (ps, b1), k in zip(entries, counts):
+                    s = ps._session
+                    w = s._frame_host(b1)
+                    h2d += w.nbytes
+                    stages += s._received_lane_stages(b1)
+                    frames.append(s._frame_device(w, k))
+                packed = jnp.concatenate(frames, axis=2) if len(frames) > 1 else frames[0]
+                # the lead engine's shard-aware budget (pow2 rounded once to
+                # the mesh shard count) — identical for every member, since
+                # the group key includes the full mesh identity + block_axes
+                packed = eng._pad_lanes(packed)
+            with span("pbvd.kernel"):
+                bits = eng._decode_blocks(packed, tuple(counts), lead._interpret)
+                subs, lo = [], 0
+                for k in counts:  # each member's bits, queued behind the kernel
+                    subs.append(jnp.transpose(bits[:, lo : lo + k]))
+                    lo += k
+            self.launches += 1
+            self.lanes_real += n_real
+            self.lanes_launched += lanes
+            self.stages_real += stages
+            self.stages_launched += n_real * (lead.cfg.D + 2 * lead.cfg.L)
+            self.h2d_bytes += h2d
+            with span("pbvd.device_wait"):  # the first copy waits for the kernel
+                outs = [np.asarray(sub, dtype=np.int32).reshape(-1) for sub in subs]
+            with span("pbvd.deliver"):
+                for (ps, b1), out in zip(entries, outs):
+                    self.d2h_bytes += out.nbytes
+                    ps._session._commit(b1)
         return outs
 
     # ---- quarantine ----------------------------------------------------------------
