@@ -12,14 +12,18 @@ by the next admit, and per-session stores that are *views* onto their page
 list rather than owners of memory.
 
 * :class:`SymbolSlab` — the allocator: ``(n_pages, page_stages, R)``
-  float32 backing array + free-list. Pages are zeroed on release, so a
-  freshly allocated page is always all-zero (the BM-neutral erasure value
-  the punctured ingest and the zero-padded tail both rely on).
+  float32 backing array + free-list, and one in-use flag per page, so the
+  range and double-free checks are O(1) whatever the free-list's length.
+  Pages are zeroed on release, so a freshly allocated page is always
+  all-zero (the BM-neutral erasure value the punctured ingest and the
+  zero-padded tail both rely on). ``free_many`` releases a run of pages in
+  one call: one check, one zeroing scatter, one extend of the free-list.
 * :class:`PagedSessionStore` — one session's buffered-symbol window,
   implementing the :class:`~repro.core.engine.ArraySessionStore` contract
   over a list of slab pages: ``append``/``grow``/``scatter`` fill the tail,
-  ``drop_prefix`` retires committed stages and returns fully consumed pages
-  to the free-list, ``read`` gathers a stage window across page boundaries.
+  ``drop_prefix`` retires committed stages and returns the fully consumed
+  prefix of pages to the free-list in one batch, ``read`` gathers a stage
+  window across page boundaries.
 
 Exhaustion is an explicit :class:`SlabExhausted` — the admission layer
 (:mod:`repro.launch.serve_async`) maps it to backpressure instead of
@@ -73,6 +77,7 @@ class SymbolSlab:
         # scatters any stage window regardless of page boundaries
         self._flat = self._data.reshape(-1, R)
         self._free: list[int] = list(range(n_pages - 1, -1, -1))  # LIFO: pop()
+        self._in_use = np.zeros(n_pages, bool)  # O(1) page state for the checks
         self.high_water = 0  # max pages simultaneously in use (for reports)
 
     # ---- allocation ----------------------------------------------------------------
@@ -92,17 +97,37 @@ class SymbolSlab:
                 f"({self.n_pages * self.page_stages} stages) in use"
             )
         page = self._free.pop()
+        self._in_use[page] = True
         self.high_water = max(self.high_water, self.pages_in_use)
         return page
 
     def free(self, page: int) -> None:
         """Return a page; zero it so the next alloc sees BM-neutral zeros."""
-        if not 0 <= page < self.n_pages:
-            raise ValueError(f"page {page} outside slab of {self.n_pages}")
-        if page in self._free:
-            raise ValueError(f"double free of slab page {page}")
-        self._data[page] = 0.0
-        self._free.append(page)
+        self.free_many((page,))
+
+    def free_many(self, pages) -> None:
+        """Return pages in one call, as ``free`` on each in order would.
+
+        Every page is checked before any state changes: a page outside the
+        slab, one not in use (never allocated or already free) or one listed
+        twice raises ``ValueError`` and leaves the slab as it was. The pages
+        are zeroed with one scatter and pushed onto the free-list in order,
+        so the next allocs hand them back last-released first.
+        """
+        pages = np.asarray(pages, np.int64).reshape(-1)
+        if not pages.size:
+            return
+        outside = (pages < 0) | (pages >= self.n_pages)
+        if outside.any():
+            raise ValueError(f"page {pages[outside.argmax()]} outside slab of {self.n_pages}")
+        idle = ~self._in_use[pages]
+        if idle.any():
+            raise ValueError(f"double free of slab page {pages[idle.argmax()]}")
+        if pages.size > 1 and np.unique(pages).size < pages.size:
+            raise ValueError("double free: a slab page listed twice in one release")
+        self._in_use[pages] = False
+        self._data[pages] = 0.0
+        self._free.extend(pages.tolist())
 
     def open_store(self) -> "PagedSessionStore":
         """A fresh (empty) session store over this slab."""
@@ -191,13 +216,14 @@ class PagedSessionStore:
         self._head += n
         self._n -= n
         P = self._slab.page_stages
-        while self._head >= P:
-            self._slab.free(self._pages.pop(0))
-            self._head -= P
+        k = self._head // P  # pages the window has fully left
+        if k:
+            self._slab.free_many(self._pages[:k])
+            del self._pages[:k]
+            self._head -= k * P
         if self._n == 0 and self._head == 0 and self._pages:
             # fully drained on a page boundary: release the idle tail page too
-            for p in self._pages:
-                self._slab.free(p)
+            self._slab.free_many(self._pages)
             self._pages.clear()
 
     def snapshot(self) -> dict:
@@ -220,8 +246,7 @@ class PagedSessionStore:
         """Return every page to the slab; safe to call repeatedly."""
         if self._closed:
             return
-        for p in self._pages:
-            self._slab.free(p)
+        self._slab.free_many(self._pages)
         self._pages.clear()
         self._head = self._n = 0
         self._closed = True

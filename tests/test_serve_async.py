@@ -164,6 +164,206 @@ def test_slab_backed_session_bit_exact_and_releases_pages():
     assert slab.pages_in_use == 0  # every page back on the free-list
 
 
+def _slab_state(slab):
+    return (list(slab._free), slab._in_use.copy(), slab._data.copy(), slab.high_water)
+
+
+def _assert_slab_state(slab, state):
+    free, in_use, data, high_water = state
+    assert slab._free == free
+    np.testing.assert_array_equal(slab._in_use, in_use)
+    np.testing.assert_array_equal(slab._data, data)
+    assert slab.high_water == high_water
+
+
+@pytest.mark.tier1
+def test_slab_single_free_refuses_bad_pages():
+    slab = SymbolSlab(n_pages=4, page_stages=3, R=2)
+    a = slab.alloc()
+    slab._data[a] = 5.0
+    state = _slab_state(slab)
+    with pytest.raises(ValueError, match="double free"):
+        slab.free(2)  # never allocated
+    with pytest.raises(ValueError, match="outside slab"):
+        slab.free(4)
+    with pytest.raises(ValueError, match="outside slab"):
+        slab.free(-1)
+    _assert_slab_state(slab, state)
+    slab.free(a)
+    with pytest.raises(ValueError, match="double free"):
+        slab.free(a)
+    assert slab.pages_free == 4 and slab.pages_in_use == 0
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize(
+    "batch, match",
+    [
+        ([0, 1, 0], "double free"),  # listed twice in one batch
+        ([0, 2, 1], "double free"),  # page 2 was freed before
+        ([1, 5], "double free"),  # page 5 was never allocated
+        ([0, 6], "outside slab"),
+        ([-1, 0], "outside slab"),
+    ],
+)
+def test_slab_free_many_refuses_a_bad_batch_and_changes_nothing(batch, match):
+    slab = SymbolSlab(n_pages=6, page_stages=3, R=2)
+    pages = [slab.alloc() for _ in range(4)]
+    assert pages == [0, 1, 2, 3]
+    slab.free(2)
+    for p in (0, 1, 3):
+        slab._data[p] = p + 1.0
+    state = _slab_state(slab)
+    with pytest.raises(ValueError, match=match):
+        slab.free_many(batch)
+    _assert_slab_state(slab, state)
+    slab.free_many([3, 0, 1])  # the pages are still held and free normally
+    assert slab._free[-3:] == [3, 0, 1] and slab.pages_in_use == 0
+    with pytest.raises(ValueError, match="double free"):
+        slab.free_many([3])
+
+
+@pytest.mark.tier1
+def test_slab_released_pages_read_zero_on_next_alloc():
+    """Pages released by free, free_many, drop_prefix and close all come
+    back all-zero from alloc."""
+    P = 4
+    slab = SymbolSlab(n_pages=12, page_stages=P, R=2)
+    single, batch = slab.alloc(), [slab.alloc() for _ in range(3)]
+    dropped, closed = slab.open_store(), slab.open_store()
+    dropped.append(np.full((3 * P + 1, 2), 3.0))
+    closed.append(np.full((2 * P, 2), 4.0))
+    slab._data[[single, *batch]] = 9.0
+    slab.free(single)
+    slab.free_many(batch)
+    dropped.drop_prefix(3 * P)  # three pages in one batch, one page kept
+    closed.close()
+    assert slab.pages_in_use == 1
+    np.testing.assert_array_equal(dropped.read(0, 1), [[3.0, 3.0]])
+    fresh = [slab.alloc() for _ in range(slab.pages_free)]
+    assert len(set(fresh)) == 11
+    assert np.all(slab._data[fresh] == 0.0)
+
+
+class _OldFreeList:
+    """The slab's page bookkeeping before batch release: a LIFO list of
+    free ids, each page pushed on its own after a scan of the list."""
+
+    def __init__(self, n_pages: int):
+        self.n_pages = n_pages
+        self.free = list(range(n_pages - 1, -1, -1))
+        self.high_water = 0
+
+    def alloc(self) -> int:
+        if not self.free:
+            raise SlabExhausted("reference slab exhausted")
+        page = self.free.pop()
+        self.high_water = max(self.high_water, self.n_pages - len(self.free))
+        return page
+
+    def release(self, page: int) -> None:
+        assert page not in self.free
+        self.free.append(page)
+
+
+class _OldPageList:
+    """One store's pages as the per-page loops kept them."""
+
+    def __init__(self, free_list: _OldFreeList, P: int):
+        self.fl, self.P = free_list, P
+        self.pages, self.head, self.n = [], 0, 0
+
+    def extend(self, n: int) -> None:
+        if n <= 0:
+            return
+        need = -(-(self.head + self.n + n) // self.P)
+        while len(self.pages) < need:
+            self.pages.append(self.fl.alloc())
+        self.n += n
+
+    def drop_prefix(self, n: int) -> None:
+        n = min(n, self.n)
+        if n <= 0:
+            return
+        self.head += n
+        self.n -= n
+        while self.head >= self.P:
+            self.fl.release(self.pages.pop(0))
+            self.head -= self.P
+        if self.n == 0 and self.head == 0 and self.pages:
+            for p in self.pages:
+                self.fl.release(p)
+            self.pages.clear()
+
+    def close(self) -> None:
+        for p in self.pages:
+            self.fl.release(p)
+        self.pages.clear()
+        self.head = self.n = 0
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize("seed, n_pages, P", [(0, 14, 5), (1, 12, 3), (2, 20, 7), (3, 10, 4)])
+def test_slab_batch_release_matches_old_per_page_free_list(seed, n_pages, P):
+    """Random append/grow/scatter/read/drop_prefix/close over several stores:
+    the page ids each store holds, the free-list's order, ``pages_free``,
+    ``high_water`` and every read match the per-page reference, exhaustion
+    included."""
+    rng = np.random.default_rng(seed)
+    R = 2
+    slab, fl = SymbolSlab(n_pages=n_pages, page_stages=P, R=R), _OldFreeList(n_pages)
+    slots = [(slab.open_store(), _OldPageList(fl, P), ArraySessionStore(R)) for _ in range(4)]
+    exhausted = 0
+    for _ in range(400):
+        i = int(rng.integers(len(slots)))
+        paged, model, content = slots[i]
+        op = int(rng.integers(0, 6))
+        if op in (0, 1):
+            n = int(rng.integers(0, 3 * P))
+            rows = rng.normal(size=(n, R)).astype(np.float32)
+            try:
+                paged.append(rows) if op == 0 else paged.grow(n)
+            except SlabExhausted:
+                exhausted += 1
+                with pytest.raises(SlabExhausted):
+                    model.extend(n)
+            else:
+                model.extend(n)
+                content.append(rows) if op == 0 else content.grow(n)
+        elif op == 2 and len(content):
+            k = int(rng.integers(1, 4))
+            si, sj = rng.integers(0, len(content), k), rng.integers(0, R, k)
+            v = rng.normal(size=k).astype(np.float32)
+            paged.scatter(si, sj, v)
+            content.scatter(si, sj, v)
+        elif op in (3, 4):
+            n = int(rng.integers(0, len(content) + 2))
+            paged.drop_prefix(n)
+            model.drop_prefix(n)
+            content.drop_prefix(n)
+        elif op == 5:
+            paged.close()
+            model.close()
+            slots[i] = (slab.open_store(), _OldPageList(fl, P), ArraySessionStore(R))
+        paged, model, content = slots[i]
+        for store, ref, _ in slots:
+            assert store._pages == ref.pages
+        assert slab._free == fl.free
+        assert slab.pages_free == len(fl.free)
+        assert slab.pages_in_use == n_pages - len(fl.free)
+        assert slab.high_water == fl.high_water
+        held = np.ones(n_pages, bool)
+        held[fl.free] = False
+        np.testing.assert_array_equal(slab._in_use, held)
+        lo = int(rng.integers(0, len(content) + 1))
+        np.testing.assert_array_equal(paged.read(lo, len(content)), content.read(lo, len(content)))
+    assert exhausted > 0  # the walk reached the slab's limit
+    for paged, model, _ in slots:
+        paged.close()
+        model.close()
+    assert slab._free == fl.free and slab.pages_in_use == 0
+
+
 # ---------------------------------------------------------------------------
 # SessionPool lifecycle: the finish paths
 # ---------------------------------------------------------------------------
