@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from repro.kernels.ops import check_mesh_launch, launched_lanes, pbvd_decode_blocks
 from repro.launch.faults import SymbolError, check_finite_symbols
+from repro.launch.spans import span
 from .codespec import CodeSpec
 
 __all__ = ["ArraySessionStore", "DecoderEngine", "DecoderSession"]
@@ -182,6 +183,28 @@ class DecoderEngine:
             if mesh is not None
             else 1
         )
+        # what the mesh launch has cost so far: launches traced and built
+        # (misses of its jit cache) and bytes of framed lanes placed on the mesh
+        self.mesh_builds = 0
+        self.shard_bytes = 0
+        if mesh is not None:
+            from repro.sharding.smap import lane_shard_map, lane_sharding
+
+            self._lane_sharding = lane_sharding(mesh, self.block_axes, 3)
+
+            def build(blocks, code, launch):
+                # runs once per trace: a new lane shape, code or launch knob
+                self.mesh_builds += 1
+                kw = dict(launch)
+                return lane_shard_map(
+                    lambda y_local: pbvd_decode_blocks(y_local, code, **kw),
+                    mesh=mesh,
+                    axes=self.block_axes,
+                    in_rank=3,
+                    out_rank=2,
+                )(blocks)
+
+            self._mesh_launch = jax.jit(build, static_argnums=(1, 2))
 
     # ------------------------------------------------------------------ one-shot
     def decode(self, y, n_bits: int | None = None, *, interpret: bool | None = None):
@@ -368,8 +391,11 @@ class DecoderEngine:
         axis (one entry for plain decodes); lanes beyond the real blocks are
         padding the backend trims. With a mesh bound, the lane axis arrives
         pre-padded to :meth:`_lane_budget` (every caller rounds once, before
-        launch) and is split over ``block_axes`` by ``shard_map`` —
-        collective-free, since blocks never interact.
+        launch), is placed on the mesh split over ``block_axes`` (span
+        ``pbvd.shard``, counted in ``shard_bytes``), and runs through the
+        engine's one jitted ``shard_map`` launch, traced and built once per
+        lane shape (``mesh_builds``) — collective-free, since blocks never
+        interact.
         """
         cfg = self.cfg
         launch_kwargs = dict(
@@ -390,8 +416,6 @@ class DecoderEngine:
                 blocks, self.spec.code, frame_counts=frame_counts, **launch_kwargs
             )
 
-        from repro.sharding.smap import lane_shard_map
-
         B = blocks.shape[2]
         if B % self.n_shards:
             # internal invariant, not a user error: decode/decode_batch/
@@ -400,18 +424,17 @@ class DecoderEngine:
                 f"lane axis {B} not divisible into {self.n_shards} shards; "
                 f"callers must pad to _lane_budget before launch"
             )
+        # the jitted launch refuses lanes committed to one device when its
+        # mapped axis spans several, so the lanes are placed here, in view
+        with span("pbvd.shard", shards=self.n_shards, lanes=B):
+            blocks = jax.device_put(blocks, self._lane_sharding)
+        self.shard_bytes += blocks.size * blocks.dtype.itemsize
         # each shard decodes its B/n_shards local lanes independently;
         # per-shard outputs must be uniform in shape, so the pad-lane trim
         # happens ONCE on the stitched result (frame_counts stays a host-side
         # concept — the mapped body decodes every local lane)
-        code = self.spec.code
-
-        def _local(y_local):
-            return pbvd_decode_blocks(y_local, code, **launch_kwargs)
-
-        bits = lane_shard_map(
-            _local, mesh=self.mesh, axes=self.block_axes, in_rank=3, out_rank=2
-        )(blocks)
+        launch = tuple(sorted(launch_kwargs.items()))
+        bits = self._mesh_launch(blocks, self.spec.code, launch)
         return bits[:, : sum(frame_counts)]
 
 

@@ -16,6 +16,12 @@ multi-slice scripts — SNIPPETS.md #2/#3):
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
         python -m repro.launch.serve_decoder --mesh data=8
 
+The measured four-chip path is the benchmark cell ``ccsds-x4.playback``
+(``bench/configs/ccsds-r12-x4.json``: one process, one service, a
+``data=4`` mesh on one TPU v5e host):
+
+    python3 bench/run.py --workload ccsds-x4.playback --seed 1 --seconds 30 --trace 0
+
 :func:`maybe_init_distributed` reads the ``JAX_COORDINATOR_ADDRESS`` /
 ``JAX_NUM_PROCESSES`` / ``JAX_PROCESS_ID`` triplet and calls
 ``jax.distributed.initialize`` when (and only when) all three are present,

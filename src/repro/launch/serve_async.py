@@ -1107,6 +1107,8 @@ class AsyncDecodeService:
             stages_launched=self._pool.stages_launched,
             h2d_bytes=self._pool.h2d_bytes,
             d2h_bytes=self._pool.d2h_bytes,
+            shard_bytes=self._pool.shard_bytes,
+            mesh_builds=self._pool.mesh_builds,
             admits=self.admits,
             admit_wait_s=self.admit_wait_s,
             bits_delivered=self._bits_delivered,

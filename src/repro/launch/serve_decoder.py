@@ -155,6 +155,10 @@ class SessionPool:
         self.stages_launched = 0
         self.h2d_bytes = 0
         self.d2h_bytes = 0
+        # on a mesh-bound engine: bytes of framed lanes placed onto the mesh,
+        # and the mesh launches traced and built for these launches
+        self.shard_bytes = 0
+        self.mesh_builds = 0
         # fault-tolerance hooks (DESIGN.md §14): ``fault_hook(entries,
         # isolating)`` is consulted before every launch (the injection point
         # for repro.launch.faults.FaultInjector); quarantined members land in
@@ -342,7 +346,10 @@ class SessionPool:
                 # the group key includes the full mesh identity + block_axes
                 packed = eng._pad_lanes(packed)
             with span("pbvd.kernel"):
+                placed, builds = eng.shard_bytes, eng.mesh_builds
                 bits = eng._decode_blocks(packed, tuple(counts), lead._interpret)
+                self.shard_bytes += eng.shard_bytes - placed
+                self.mesh_builds += eng.mesh_builds - builds
                 subs, lo = [], 0
                 for k in counts:  # each member's bits, queued behind the kernel
                     subs.append(jnp.transpose(bits[:, lo : lo + k]))
