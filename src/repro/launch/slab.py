@@ -25,6 +25,14 @@ list rather than owners of memory.
   prefix of pages to the free-list in one batch, ``read`` gathers a stage
   window across page boundaries.
 
+Reads and appends move whole pages: ``read`` copies the pages a window
+touches with one gather on the page axis and slices the partial first and
+last page off the copy; ``append`` writes at most three pieces (the rest of
+the tail page, the whole pages after it in one page-indexed assignment, and
+the start of the last page). Their cost grows with the bytes moved, not with
+a per-row index. A read never aliases the slab, and an append either writes
+every row or, when the slab runs out of pages, none.
+
 Exhaustion is an explicit :class:`SlabExhausted` — the admission layer
 (:mod:`repro.launch.serve_async`) maps it to backpressure instead of
 letting the slab grow unboundedly.
@@ -73,9 +81,6 @@ class SymbolSlab:
         self.page_stages = int(page_stages)
         self.R = int(R)
         self._data = np.zeros((n_pages, page_stages, R), np.float32)
-        # flat (n_pages*page_stages, R) alias: one fancy-index gathers or
-        # scatters any stage window regardless of page boundaries
-        self._flat = self._data.reshape(-1, R)
         self._free: list[int] = list(range(n_pages - 1, -1, -1))  # LIFO: pop()
         self._in_use = np.zeros(n_pages, bool)  # O(1) page state for the checks
         self.high_water = 0  # max pages simultaneously in use (for reports)
@@ -145,6 +150,12 @@ class PagedSessionStore:
     steady-state stream touches exactly ceil(working set / P) pages no
     matter how many chunks flow through it.
 
+    ``read`` and ``append`` address whole pages, with slices for the partial
+    pages at either edge of the window. ``read`` returns a fresh float32
+    array (writing to it leaves the slab as it was). ``append`` reserves
+    every page it needs before it writes a symbol, so a
+    :class:`SlabExhausted` leaves the held stages and ``len`` unchanged.
+
     Implements the :class:`~repro.core.engine.ArraySessionStore` contract;
     see that class for method semantics.
     """
@@ -159,13 +170,7 @@ class PagedSessionStore:
     def __len__(self) -> int:
         return self._n
 
-    # ---- row addressing ------------------------------------------------------------
-    def _rows(self, lo: int, n: int) -> np.ndarray:
-        """Flat slab row indices for logical stages [lo, lo+n)."""
-        g = self._head + lo + np.arange(n)
-        pages = np.asarray(self._pages, np.int64)[g // self._slab.page_stages]
-        return pages * self._slab.page_stages + g % self._slab.page_stages
-
+    # ---- page addressing -----------------------------------------------------------
     def _ensure_capacity(self, n_total: int) -> None:
         """Grow the page list to hold ``n_total`` logical stages."""
         P = self._slab.page_stages
@@ -176,12 +181,26 @@ class PagedSessionStore:
     # ---- ArraySessionStore contract ------------------------------------------------
     def append(self, rows: np.ndarray) -> None:
         self._check_open()
-        rows = np.asarray(rows, np.float32)
+        rows = np.asarray(rows)
         n = len(rows)
         if n == 0:
             return
-        self._ensure_capacity(self._n + n)
-        self._slab._flat[self._rows(self._n, n)] = rows
+        rows = np.broadcast_to(rows, (n, self._slab.R))  # a bad shape fails before any write
+        self._ensure_capacity(self._n + n)  # so does an exhausted slab
+        data, P = self._slab._data, self._slab.page_stages
+        p, r = divmod(self._head + self._n, P)
+        done = 0
+        if r:  # the rest of the tail page
+            done = min(P - r, n)
+            data[self._pages[p], r : r + done] = rows[:done]
+            p += 1
+        k = (n - done) // P
+        if k:  # whole pages, one page-indexed assignment (casts to float32)
+            data[self._pages[p : p + k]] = rows[done : done + k * P].reshape(k, P, -1)
+            p += k
+            done += k * P
+        if done < n:  # the start of the last page
+            data[self._pages[p], : n - done] = rows[done:]
         self._n += n
 
     def grow(self, n: int) -> None:
@@ -199,14 +218,19 @@ class PagedSessionStore:
         g = self._head + stage_idx
         P = self._slab.page_stages
         pages = np.asarray(self._pages, np.int64)[g // P]
-        self._slab._flat[pages * P + g % P, sym_idx] = values
+        self._slab._data[pages, g % P, sym_idx] = values
 
     def read(self, lo: int, n: int) -> np.ndarray:
         self._check_open()
         n = max(0, min(n, self._n - lo))
         if n <= 0:
             return np.zeros((0, self._slab.R), np.float32)
-        return self._slab._flat[self._rows(lo, n)]
+        P = self._slab.page_stages
+        g = self._head + lo
+        p0, p1 = g // P, -(-(g + n) // P)
+        # one gather on the page axis copies the pages; slice the edges off
+        pages = np.take(self._slab._data, self._pages[p0:p1], axis=0)
+        return pages.reshape(-1, self._slab.R)[g - p0 * P : g - p0 * P + n]
 
     def drop_prefix(self, n: int) -> None:
         self._check_open()

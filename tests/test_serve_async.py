@@ -364,6 +364,136 @@ def test_slab_batch_release_matches_old_per_page_free_list(seed, n_pages, P):
     assert slab._free == fl.free and slab.pages_in_use == 0
 
 
+class _RowIndexStore(PagedSessionStore):
+    """The store as it addressed stages before whole-page copies: a flat
+    row index over every stage of a window, then one fancy gather or
+    scatter of rows."""
+
+    def _rows(self, lo: int, n: int) -> np.ndarray:
+        P = self._slab.page_stages
+        g = self._head + lo + np.arange(n)
+        return np.asarray(self._pages, np.int64)[g // P] * P + g % P
+
+    def append(self, rows) -> None:
+        rows = np.asarray(rows, np.float32)
+        if len(rows):
+            self._ensure_capacity(self._n + len(rows))
+            self._slab._data.reshape(-1, self._slab.R)[self._rows(self._n, len(rows))] = rows
+            self._n += len(rows)
+
+    def read(self, lo: int, n: int) -> np.ndarray:
+        n = max(0, min(n, self._n - lo))
+        return self._slab._data.reshape(-1, self._slab.R)[self._rows(lo, n)]
+
+
+def _read_window(rng, held: int, head: int, P: int) -> tuple[int, int]:
+    """A read of one of the shapes framing and recovery make: random, mid-page
+    to mid-page over many pages, exactly one page, empty, or past the end."""
+    kind = int(rng.integers(5))
+    lo = int(rng.integers(0, held + 1))
+    if kind == 1 and held >= 2 * P:  # many pages, starting and ending mid-page
+        lo = int(rng.integers(0, P))
+        return lo, int(rng.integers(P + 1, held - lo + 1))
+    if kind == 2 and held >= 2 * P:  # exactly one whole page
+        return (-head) % P + P * int(rng.integers(0, (held - (-head) % P) // P)), P
+    if kind == 3:
+        return lo, 0
+    if kind == 4:  # runs past the held end: clipped
+        return lo, held - lo + int(rng.integers(1, 2 * P + 2))
+    return lo, int(rng.integers(0, held - lo + 1))
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize("seed, P", [(0, 1), (1, 3), (2, 596), (3, 1), (4, 3), (5, 596)])
+def test_paged_read_append_match_row_index_reference(seed, P):
+    """A seeded walk of int8 and float32 appends, prefix drops and reads: the
+    whole-page store reads what the per-row index store reads and leaves the
+    same slab contents, for page sizes of 1, 3 and 596 stages."""
+    rng = np.random.default_rng(seed)
+    R, n_pages = 2, 64
+    slab, ref_slab = SymbolSlab(n_pages, P, R), SymbolSlab(n_pages, P, R)
+    store, ref = slab.open_store(), _RowIndexStore(ref_slab)
+    heads = set()
+    for _ in range(120):
+        if len(store) < (n_pages // 2) * P and rng.integers(3):
+            n = int(rng.integers(0, 4 * P + 3))
+            if rng.integers(2):
+                rows = rng.integers(-127, 128, (n, R)).astype(np.int8)
+            else:
+                rows = rng.normal(size=(n, R)).astype(np.float32)
+            store.append(rows)
+            ref.append(rows)
+        else:
+            n = int(rng.integers(0, len(store) + 1))
+            store.drop_prefix(n)
+            ref.drop_prefix(n)
+        heads.add(store._head)
+        assert len(store) == len(ref) and store._pages == ref._pages
+        np.testing.assert_array_equal(slab._data, ref_slab._data)
+        for _ in range(3):
+            lo, n = _read_window(rng, len(store), store._head, P)
+            got = store.read(lo, n)
+            assert got.dtype == np.float32 and got.shape == (max(0, min(n, len(store) - lo)), R)
+            np.testing.assert_array_equal(got, ref.read(lo, n))
+    assert P == 1 or len(heads - {0}) > 0  # appends landed after mid-page drops
+    store.close()
+    assert slab.pages_in_use == 0
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize("held", [0, 2, 4, 6])
+def test_paged_append_refused_mid_chunk_writes_nothing(held):
+    """An append that runs out of pages partway through its chunk raises
+    SlabExhausted with no symbol written and ``len`` unchanged."""
+    P = 4
+    slab = SymbolSlab(n_pages=3, page_stages=P, R=2)
+    store = slab.open_store()
+    store.append(np.full((held, 2), 5.0, np.float32))
+    data, before = slab._data.copy(), store.read(0, held)
+    with pytest.raises(SlabExhausted):  # one stage more than the whole slab
+        store.append(np.arange((3 * P + 1) * 2, dtype=np.int8).reshape(-1, 2) - 50)
+    assert len(store) == held
+    np.testing.assert_array_equal(slab._data, data)
+    np.testing.assert_array_equal(store.read(0, held + 3 * P), before)
+    store.append(np.ones((1, 2), np.int8))  # a chunk that fits still lands
+    np.testing.assert_array_equal(store.read(held, 1), [[1.0, 1.0]])
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize("lo, n", [(1, 2), (3, 4), (2, 9)])  # in a page, one page, across pages
+def test_paged_read_does_not_alias_slab(lo, n):
+    slab = SymbolSlab(n_pages=4, page_stages=4, R=2)
+    store = slab.open_store()
+    store.append(np.arange(26, dtype=np.float32).reshape(13, 2))
+    store.drop_prefix(1)  # head mid-page, so lo=3 reads exactly page 1
+    data, first = slab._data.copy(), store.read(lo, n)
+    first[...] = -1.0
+    np.testing.assert_array_equal(slab._data, data)
+    np.testing.assert_array_equal(store.read(lo, n), data.reshape(-1, 2)[1 + lo : 1 + lo + n])
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize("P, drop, held", [(4, 3, 9), (5, 7, 11), (596, 590, 700)])
+def test_paged_snapshot_restore_across_page_boundary(P, drop, held):
+    """A window that starts mid-page and crosses a page boundary snapshots
+    and restores into a fresh store (head 0) with every stage intact."""
+    rng = np.random.default_rng(P)
+    rows = rng.integers(-127, 128, (drop + held, 2)).astype(np.int8)
+    src = SymbolSlab(n_pages=4, page_stages=P, R=2).open_store()
+    src.append(rows)
+    src.drop_prefix(drop)
+    assert src._head and src._head + held > P
+    snap = src.snapshot()
+    dst_slab = SymbolSlab(n_pages=4, page_stages=P, R=2)
+    dst = dst_slab.open_store()
+    dst.restore(snap)
+    assert dst._head == 0 and len(dst) == held
+    np.testing.assert_array_equal(dst.read(0, held), rows[drop:].astype(np.float32))
+    np.testing.assert_array_equal(dst.read(0, held), src.read(0, held))
+    snap["rows"][...] = 0.0  # the snapshot is a copy, not a view of either slab
+    np.testing.assert_array_equal(dst.read(0, held), rows[drop:].astype(np.float32))
+
+
 # ---------------------------------------------------------------------------
 # SessionPool lifecycle: the finish paths
 # ---------------------------------------------------------------------------
