@@ -4,244 +4,68 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 " + os.environ.get("XLA_FLAGS", "")
 )
 
-"""Multi-pod dry-run (assignment deliverable e).
+"""Multi-pod dry-run of the PBVD decoder.
 
-Lowers + compiles every (architecture × input shape) cell against the
-production meshes — 16×16 (single pod, 256 chips) and 2×16×16 (two pods,
-512 chips) — using ShapeDtypeStruct inputs only (no allocation), then
-records memory analysis, cost analysis and the HLO-derived roofline terms.
+Lowers + compiles the decoder's lane launch against the production meshes
+— 16×16 (single pod, 256 chips) and 2×16×16 (two pods, 512 chips) — using
+ShapeDtypeStruct inputs only (no allocation), then records memory analysis
+and the HLO-derived roofline terms.
 
 Usage:
-  python -m repro.launch.dryrun --arch qwen2.5-32b --shape train_4k [--multi-pod]
-  python -m repro.launch.dryrun --all [--multi-pod] [--resume]   # batch driver
-  python -m repro.launch.dryrun --viterbi                        # decoder cells
+  python -m repro.launch.dryrun --viterbi                       # every cell
+  python -m repro.launch.dryrun --shape stream_16m_int8 [--multi-pod] [--out F]
 
-The batch driver runs every cell in a fresh subprocess (XLA state isolation
-+ peak-RSS control on the 1-core CPU container) and writes one JSON report
-per cell under reports/dryrun/.
+``--viterbi`` writes one JSON report per cell under reports/dryrun/.
 """
 
 import argparse
-import dataclasses
 import json
-import subprocess
-import sys
 import time
-import traceback
 from pathlib import Path
 
 REPORTS = Path(__file__).resolve().parents[3] / "reports" / "dryrun"
+VARIANTS = {"stream_16m_int8": 32768, "stream_4m_f32": 8192}  # cell → lanes
 
 
-def _cell_report_path(arch: str, shape: str, multi_pod: bool) -> Path:
+def _cell_report_path(shape: str, multi_pod: bool) -> Path:
     mesh = "pod2x16x16" if multi_pod else "pod16x16"
-    return REPORTS / f"{arch}__{shape}__{mesh}.json"
-
-
-# ======================================================================================
-# single-cell runner (executes inside the subprocess)
-# ======================================================================================
-def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
-    import jax
-    import jax.numpy as jnp
-
-    from repro.configs.base import SHAPES
-    from repro.launch.hlo_analysis import analyze_hlo
-    from repro.launch.mesh import make_production_mesh
-    from repro.launch.specs import ENCODER_CTX, SKIPS, input_specs, make_cell
-    from repro.sharding.rules import axis_rules, tree_shardings
-    from repro._unused.models import lm
-    from repro._unused.serve.serve_step import make_decode_step, make_prefill_step
-    from repro._unused.train.optimizer import AdamWConfig, adamw_init
-    from repro._unused.train.train_step import make_train_step
-
-    t0 = time.time()
-    if (arch, shape_name) in SKIPS:
-        return {
-            "arch": arch, "shape": shape_name, "mesh": "2x16x16" if multi_pod else "16x16",
-            "status": "skip", "reason": SKIPS[(arch, shape_name)],
-        }
-
-    cell = make_cell(arch, shape_name)
-    cfg = cell.cfg
-    mesh = make_production_mesh(multi_pod=multi_pod)
-    n_chips = mesh.devices.size
-    specs = input_specs(cell)
-
-    # §Perf A/B knobs: REPRO_MOE_RULES=fsdp disables expert parallelism
-    # (experts replicated / FSDP-gathered — the naive baseline);
-    # REPRO_BF16_GATHER=0 keeps f32 FSDP gathers (paper-typical baseline).
-    rules_override = None
-    if os.environ.get("REPRO_MOE_RULES") == "fsdp":
-        from repro.sharding.rules import DEFAULT_RULES, SINGLE_POD_RULES
-
-        base = DEFAULT_RULES if multi_pod else SINGLE_POD_RULES
-        rules_override = dict(base, experts=None)
-    bf16_gather = os.environ.get("REPRO_BF16_GATHER", "1") != "0"
-
-    with axis_rules(mesh, rules_override) as rules:
-        paxes = lm.param_axes(cfg)
-        pspec = tree_shardings(specs["params"], paxes, rules)
-
-        if cell.kind == "train":
-            opt_cfg = AdamWConfig()
-            opt_specs = jax.eval_shape(lambda p: adamw_init(p, opt_cfg), specs["params"])
-            repl = jax.NamedSharding(mesh, jax.sharding.PartitionSpec())
-            from repro._unused.train.optimizer import OptState
-
-            oshard = OptState(step=repl, m=pspec, v=pspec)
-            bshard = {
-                k: jax.NamedSharding(
-                    mesh, rules.spec(("batch",) + (None,) * (len(v.shape) - 1), shape=v.shape)
-                )
-                for k, v in specs["batch"].items()
-            }
-            step = make_train_step(cfg, opt_cfg, bf16_gather=bf16_gather)
-            jitted = jax.jit(
-                step,
-                in_shardings=(pspec, oshard, bshard),
-                out_shardings=(pspec, oshard, None),
-                donate_argnums=(0, 1),
-            )
-            args = (specs["params"], opt_specs, specs["batch"])
-        elif cell.kind == "prefill":
-            bshard = {
-                k: jax.NamedSharding(
-                    mesh, rules.spec(("batch",) + (None,) * (len(v.shape) - 1), shape=v.shape)
-                )
-                for k, v in specs["batch"].items()
-            }
-            step = make_prefill_step(cfg)
-            jitted = jax.jit(
-                step,
-                in_shardings=(pspec, bshard),
-                out_shardings=jax.NamedSharding(
-                    mesh, rules.spec(("batch",), shape=(cell.shape.global_batch,))
-                ),
-            )
-            args = (specs["params"], specs["batch"])
-        else:  # decode
-            ctx_parallel = cell.shape.seq_len >= (1 << 15)
-            caxes = lm.cache_axes(cfg, ctx_parallel=ctx_parallel, cross=cfg.encdec)
-            cspec = tree_shardings(specs["cache"], caxes, rules)
-            B = cell.shape.global_batch
-            tshard = jax.NamedSharding(mesh, rules.spec(("batch", None), shape=(B, 1)))
-            repl = jax.NamedSharding(mesh, jax.sharding.PartitionSpec())
-            step = make_decode_step(cfg, cell.shape.seq_len)
-            jitted = jax.jit(
-                step,
-                in_shardings=(pspec, tshard, cspec, repl),
-                out_shardings=(
-                    jax.NamedSharding(mesh, rules.spec(("batch",), shape=(B,))),
-                    cspec,
-                ),
-                donate_argnums=(2,),
-            )
-            args = (specs["params"], specs["tokens"], specs["cache"], specs["cache_len"])
-
-        lowered = jitted.lower(*args)
-        t_lower = time.time() - t0
-        compiled = lowered.compile()
-        t_compile = time.time() - t0 - t_lower
-
-    # ---- analyses -------------------------------------------------------------------
-    report = {
-        "arch": arch,
-        "shape": shape_name,
-        "mesh": "2x16x16" if multi_pod else "16x16",
-        "n_chips": int(n_chips),
-        "kind": cell.kind,
-        "status": "ok",
-        "lower_s": round(t_lower, 2),
-        "compile_s": round(t_compile, 2),
-    }
-    try:
-        ma = compiled.memory_analysis()
-        if ma is not None:
-            for k in (
-                "temp_size_in_bytes", "argument_size_in_bytes", "output_size_in_bytes",
-                "alias_size_in_bytes", "generated_code_size_in_bytes",
-            ):
-                v = getattr(ma, k, None)
-                if v is not None:
-                    report.setdefault("memory", {})[k] = int(v)
-    except Exception as e:  # noqa: BLE001
-        report["memory_error"] = str(e)
-    try:
-        ca = compiled.cost_analysis()
-        if ca:
-            report["cost_analysis"] = {
-                k: float(v) for k, v in ca.items()
-                if k in ("flops", "bytes accessed", "transcendentals") or k.startswith("bytes accessed")
-            }
-    except Exception as e:  # noqa: BLE001
-        report["cost_error"] = str(e)
-
-    hlo = compiled.as_text()
-    st = analyze_hlo(hlo)
-    report["hlo"] = {
-        "flops_per_device": st.flops,
-        "bytes_per_device": st.bytes_accessed,
-        "collective_bytes_per_device": st.collective_bytes,
-        "collective_counts": st.collective_counts,
-        "n_while": st.n_while,
-        "trip_counts": st.trip_counts,
-        "hlo_chars": len(hlo),
-    }
-
-    # model FLOPs (roofline §: 6·N_active·D for train, 2·N_active·D otherwise)
-    n_active = cfg.n_active_params_estimate
-    B, S = cell.shape.global_batch, cell.shape.seq_len
-    if cell.kind == "train":
-        tokens = B * S
-        model_flops = 6.0 * n_active * tokens
-    elif cell.kind == "prefill":
-        tokens = B * S
-        model_flops = 2.0 * n_active * tokens
-    else:
-        tokens = B  # one token per sequence
-        model_flops = 2.0 * n_active * tokens
-    report["model_flops_global"] = model_flops
-    report["tokens_per_step"] = tokens
-    report["n_active_params"] = n_active
-    report["total_s"] = round(time.time() - t0, 2)
-    return report
+    return REPORTS / f"viterbi-ccsds__{shape}__{mesh}.json"
 
 
 def run_viterbi_cell(variant: str, multi_pod: bool) -> dict:
-    """Dry-run the PBVD decoder as a data-plane workload on the same mesh."""
+    """Dry-run the PBVD decoder's lane launch on a production mesh."""
     import jax
     import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
 
     from repro.core.trellis import CCSDS_27
     from repro.kernels.ops import pbvd_decode_blocks
     from repro.launch.hlo_analysis import analyze_hlo
     from repro.launch.mesh import make_production_mesh
-    from repro.sharding.rules import axis_rules
+    from repro.sharding.rules import block_mesh_axes
 
     t0 = time.time()
     mesh = make_production_mesh(multi_pod=multi_pod)
     D, L = 512, 42
     T = D + 2 * L
-    n_blocks = {"stream_16m_int8": 32768, "stream_4m_f32": 8192}[variant]
+    n_blocks = VARIANTS[variant]
     dtype = jnp.int8 if variant.endswith("int8") else jnp.float32
 
-    with axis_rules(mesh) as rules:
-        bspec = jax.NamedSharding(mesh, rules.spec((None, None, "blocks")))
+    lanes = block_mesh_axes(mesh)
 
-        def step(blocks):
-            return pbvd_decode_blocks(
-                blocks, CCSDS_27, decode_start=L, n_decode=D, backend="ref"
-            )
-
-        jitted = jax.jit(
-            step,
-            in_shardings=(bspec,),
-            out_shardings=jax.NamedSharding(mesh, rules.spec((None, "blocks"))),
+    def step(blocks):
+        return pbvd_decode_blocks(
+            blocks, CCSDS_27, decode_start=L, n_decode=D, backend="ref"
         )
-        sds = jax.ShapeDtypeStruct((T, CCSDS_27.R, n_blocks), dtype)
-        lowered = jitted.lower(sds)
-        compiled = lowered.compile()
+
+    jitted = jax.jit(
+        step,
+        in_shardings=(NamedSharding(mesh, P(None, None, lanes)),),
+        out_shardings=NamedSharding(mesh, P(None, lanes)),
+    )
+    sds = jax.ShapeDtypeStruct((T, CCSDS_27.R, n_blocks), dtype)
+    compiled = jitted.lower(sds).compile()
 
     st = analyze_hlo(compiled.as_text())
     report = {
@@ -250,7 +74,6 @@ def run_viterbi_cell(variant: str, multi_pod: bool) -> dict:
         "mesh": "2x16x16" if multi_pod else "16x16",
         "n_chips": int(mesh.devices.size),
         "kind": "decode_stream",
-        "status": "ok",
         "bits_per_step": D * n_blocks,
         "hlo": {
             "flops_per_device": st.flops,
@@ -273,96 +96,32 @@ def run_viterbi_cell(variant: str, multi_pod: bool) -> dict:
     return report
 
 
-# ======================================================================================
-# batch driver
-# ======================================================================================
-def _run_subprocess(arch: str, shape: str, multi_pod: bool, timeout: int = 3000) -> dict:
-    out_path = _cell_report_path(arch, shape, multi_pod)
-    out_path.parent.mkdir(parents=True, exist_ok=True)
-    cmd = [
-        sys.executable, "-m", "repro.launch.dryrun",
-        "--arch", arch, "--shape", shape, "--out", str(out_path),
-    ]
-    if multi_pod:
-        cmd.append("--multi-pod")
-    env = dict(os.environ)
-    t0 = time.time()
-    try:
-        proc = subprocess.run(cmd, capture_output=True, text=True, timeout=timeout, env=env)
-        if out_path.exists():
-            return json.loads(out_path.read_text())
-        report = {
-            "arch": arch, "shape": shape, "mesh": "2x16x16" if multi_pod else "16x16",
-            "status": "error",
-            "error": (proc.stderr or proc.stdout or "")[-2000:],
-            "total_s": round(time.time() - t0, 2),
-        }
-    except subprocess.TimeoutExpired:
-        report = {
-            "arch": arch, "shape": shape, "mesh": "2x16x16" if multi_pod else "16x16",
-            "status": "timeout", "total_s": round(time.time() - t0, 2),
-        }
-    out_path.write_text(json.dumps(report, indent=2))
-    return report
-
-
 def main() -> None:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch")
-    ap.add_argument("--shape")
+    ap = argparse.ArgumentParser(description="Multi-pod dry-run of the PBVD decoder.")
+    ap.add_argument("--viterbi", action="store_true", help="dry-run every cell")
+    ap.add_argument("--shape", choices=list(VARIANTS), help="dry-run one cell")
     ap.add_argument("--multi-pod", action="store_true")
-    ap.add_argument("--all", action="store_true")
-    ap.add_argument("--viterbi", action="store_true")
-    ap.add_argument("--resume", action="store_true")
     ap.add_argument("--out")
     args = ap.parse_args()
 
-    if args.all:
-        from repro.configs.base import SHAPES, list_archs  # no jax needed
-
-        cells = [(a, s) for a in list_archs() for s in SHAPES]
-        for mp in ([False, True]):
-            for arch, shape in cells:
-                path = _cell_report_path(arch, shape, mp)
-                if args.resume and path.exists():
-                    r = json.loads(path.read_text())
-                    if r.get("status") in ("ok", "skip"):
-                        continue
-                r = _run_subprocess(arch, shape, mp)
-                print(
-                    f"[{r.get('status','?'):7s}] {arch} × {shape} × {r.get('mesh')}"
-                    f"  ({r.get('total_s', '?')}s)",
-                    flush=True,
-                )
-        return
-
     if args.viterbi:
         for mp in (False, True):
-            for variant in ("stream_16m_int8", "stream_4m_f32"):
+            for variant in VARIANTS:
                 r = run_viterbi_cell(variant, mp)
-                p = _cell_report_path("viterbi-ccsds", variant, mp)
+                p = _cell_report_path(variant, mp)
                 p.parent.mkdir(parents=True, exist_ok=True)
                 p.write_text(json.dumps(r, indent=2))
-                print(f"[{r['status']:7s}] viterbi × {variant} × {r['mesh']} ({r['total_s']}s)", flush=True)
+                print(f"[ok] viterbi × {variant} × {r['mesh']} ({r['total_s']}s)", flush=True)
         return
+    if args.shape is None:
+        ap.error("give --viterbi or --shape")
 
-    if args.arch == "viterbi-ccsds":
-        report = run_viterbi_cell(args.shape, args.multi_pod)
-    else:
-        try:
-            report = run_cell(args.arch, args.shape, args.multi_pod)
-        except Exception:  # noqa: BLE001
-            report = {
-                "arch": args.arch, "shape": args.shape,
-                "mesh": "2x16x16" if args.multi_pod else "16x16",
-                "status": "error", "error": traceback.format_exc()[-4000:],
-            }
+    report = run_viterbi_cell(args.shape, args.multi_pod)
     text = json.dumps(report, indent=2)
     if args.out:
         Path(args.out).parent.mkdir(parents=True, exist_ok=True)
         Path(args.out).write_text(text)
     print(text)
-    sys.exit(0 if report.get("status") in ("ok", "skip") else 1)
 
 
 if __name__ == "__main__":

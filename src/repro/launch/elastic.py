@@ -1,36 +1,25 @@
-"""Elastic scaling: rebuild the mesh after node loss/gain and reshard state.
+"""Elastic scaling for a decode fleet: a smaller mesh after chip loss.
 
-Strategy (single-controller JAX):
-  * the `model` axis extent is fixed (TP degree is baked into layer math
-    perf-wise, but **not** into the checkpoint — shards are reassembled to
-    global arrays on restore, so even TP can change);
-  * the (`pod` × `data`) product absorbs failures: losing a host rebuilds a
-    mesh with a smaller `data` extent, restores the latest checkpoint with
-    the new shardings, and rescales the data pipeline (`host_count` drops);
-  * a failed step is retried from the last checkpoint — see
-    launch/train.py's failure loop (tested with failure injection).
-
-`plan_rescale` computes the largest valid mesh after losing `lost` chips;
-`reshard` moves a live pytree onto a new mesh (host round-trip — the
-simple, always-correct path; production would use device-to-device
-resharding collectives).
+`plan_rescale` computes the largest valid mesh after losing `lost` chips
+by shrinking only some of the mesh's axes; `plan_decode_rescale` restricts
+that to the axes that carry the decoder's lanes, and
+`rescale_decode_engine` rebuilds an engine on the plan's mesh (or drops it
+to meshless dispatch). The serving layer's mesh-loss fallback
+(`serve_async.AsyncDecodeService`) swaps engines this way under live
+sessions; the decode is bit-exact on any mesh, so nothing is resharded.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
 
 import jax
 import numpy as np
-
-from repro.sharding.rules import LogicalRules, tree_shardings
 
 __all__ = [
     "plan_rescale",
     "plan_decode_rescale",
     "rescale_decode_engine",
-    "reshard",
     "RescalePlan",
 ]
 
@@ -56,8 +45,7 @@ def plan_rescale(
     """Largest mesh obtainable by shrinking ``shrink_axes`` after losing
     ``lost_chips`` devices.
 
-    ``shrink_axes`` defaults to every axis except ``model`` (the train-mesh
-    contract above: TP degree is baked into layer math). A decode fleet
+    ``shrink_axes`` defaults to every axis except ``model``. A decode fleet
     passes its engine's ``block_axes`` instead — the lane axis is the only
     thing a PBVD mesh shards, so those are the axes a casualty can shrink
     (see :func:`plan_decode_rescale`).
@@ -160,14 +148,3 @@ def rescale_decode_engine(engine, lost_chips: int):
     new_mesh = shrink_mesh(engine.mesh, plan.new_shape)
     return DecoderEngine(engine.cfg, mesh=new_mesh, block_axes=engine.block_axes)
 
-
-def reshard(tree: Any, axes_tree: Any, new_mesh: jax.sharding.Mesh, rules_map=None) -> Any:
-    """Move a pytree onto ``new_mesh`` with its logical axes re-resolved."""
-    from repro.sharding.rules import DEFAULT_RULES, SINGLE_POD_RULES
-
-    if rules_map is None:
-        rules_map = DEFAULT_RULES if "pod" in new_mesh.axis_names else SINGLE_POD_RULES
-    rules = LogicalRules(new_mesh, rules_map)
-    shardings = tree_shardings(tree, axes_tree, rules)
-    host = jax.tree.map(lambda l: np.asarray(l), tree)
-    return jax.tree.map(jax.device_put, host, shardings)

@@ -1,8 +1,7 @@
 """Multi-device semantics tests.
 
 Each test runs in a subprocess with ``--xla_force_host_platform_device_count=8``
-(the main pytest process stays single-device, per the assignment's rule that
-only the dry-run sees fake devices).
+(the main pytest process stays single-device).
 """
 
 import os
@@ -54,107 +53,6 @@ def test_sharded_decode_stream_matches_unsharded():
         out = np.asarray(decode_stream_sharded(y, n, cfg, mesh))
         assert np.array_equal(ref, out), "sharded decode diverged"
         print("ok")
-    """)
-
-
-def test_sharded_train_step_matches_single_device():
-    """pjit train step on a 4×2 mesh reproduces single-device numerics."""
-    _run("""
-        import jax, jax.numpy as jnp, numpy as np, dataclasses
-        from repro.configs.base import get_config
-        from repro._unused.models import lm
-        from repro.sharding.rules import axis_rules, tree_shardings
-        from repro._unused.train.optimizer import AdamWConfig, adamw_init
-        from repro._unused.train.train_step import make_train_step
-
-        cfg = dataclasses.replace(get_config("minitron-8b").reduced(), compute_dtype="float32")
-        opt_cfg = AdamWConfig(warmup_steps=1, total_steps=10)
-        params = lm.init_params(jax.random.PRNGKey(0), cfg)
-        opt = adamw_init(params, opt_cfg)
-        rng = np.random.default_rng(0)
-        batch = {
-            "tokens": jnp.asarray(rng.integers(0, cfg.vocab, (8, 32)), jnp.int32),
-            "labels": jnp.asarray(rng.integers(0, cfg.vocab, (8, 32)), jnp.int32),
-        }
-        step = make_train_step(cfg, opt_cfg)
-        p1, o1, m1 = jax.jit(step)(params, opt, batch)
-
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
-        with axis_rules(mesh) as rules:
-            paxes = lm.param_axes(cfg)
-            pshard = tree_shardings(params, paxes, rules)
-            params_s = jax.tree.map(jax.device_put, params, pshard)
-            opt_s = adamw_init(params_s, opt_cfg)
-            p2, o2, m2 = jax.jit(step)(params_s, opt_s, batch)
-        assert abs(float(m1["loss"]) - float(m2["loss"])) < 1e-4, (m1["loss"], m2["loss"])
-        for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
-        print("ok", float(m1["loss"]))
-    """)
-
-
-def test_pipeline_parallel_matches_sequential():
-    """GPipe pipeline over 4 stages == sequential stage composition."""
-    _run("""
-        import jax, jax.numpy as jnp, numpy as np
-        from repro.launch.pp import pipeline_apply, bubble_fraction
-
-        mesh = jax.make_mesh((4,), ("pipe",))
-        P, M, mb, d = 4, 6, 2, 16
-        rng = np.random.default_rng(0)
-        ws = jnp.asarray(rng.normal(size=(P, d, d)).astype(np.float32) * 0.3)
-        x = jnp.asarray(rng.normal(size=(M, mb, d)).astype(np.float32))
-
-        def stage(w, h):
-            return jnp.tanh(h @ w)
-
-        out = pipeline_apply(stage, ws, x, mesh, axis="pipe")
-        ref = x
-        for s in range(P):
-            ref = jnp.tanh(ref @ ws[s])
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
-        assert abs(bubble_fraction(4, 6) - 3/9) < 1e-9
-        print("ok")
-    """)
-
-
-def test_dryrun_smoke_tiny_mesh():
-    """The dry-run machinery itself (specs → shardings → lower → analyze)
-    works on an 8-device mesh with a reduced config."""
-    _run("""
-        import jax, jax.numpy as jnp
-        from repro.configs.base import get_config
-        from repro._unused.models import lm
-        from repro.sharding.rules import axis_rules, tree_shardings
-        from repro.launch.hlo_analysis import analyze_hlo
-        from repro._unused.train.optimizer import AdamWConfig, adamw_init, OptState
-        from repro._unused.train.train_step import make_train_step
-
-        cfg = get_config("mixtral-8x22b").reduced()
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
-        with axis_rules(mesh) as rules:
-            params_sds = jax.eval_shape(lambda k: lm.init_params(k, cfg), jax.random.PRNGKey(0))
-            pshard = tree_shardings(params_sds, lm.param_axes(cfg), rules)
-            opt_cfg = AdamWConfig()
-            opt_sds = jax.eval_shape(lambda p: adamw_init(p, opt_cfg), params_sds)
-            repl = jax.NamedSharding(mesh, jax.sharding.PartitionSpec())
-            oshard = OptState(step=repl, m=pshard, v=pshard)
-            batch = {
-                "tokens": jax.ShapeDtypeStruct((8, 64), jnp.int32),
-                "labels": jax.ShapeDtypeStruct((8, 64), jnp.int32),
-            }
-            bshard = {k: jax.NamedSharding(mesh, rules.spec(("batch", None))) for k in batch}
-            step = make_train_step(cfg, opt_cfg)
-            compiled = jax.jit(
-                step, in_shardings=(pshard, oshard, bshard),
-                out_shardings=(pshard, oshard, None),
-            ).lower(params_sds, opt_sds, batch).compile()
-            st = analyze_hlo(compiled.as_text())
-            assert st.flops > 0
-            assert st.total_collective_bytes > 0, "expected collectives on a 4x2 mesh"
-            ma = compiled.memory_analysis()
-            assert ma is not None
-        print("ok", st.flops, st.total_collective_bytes)
     """)
 
 
